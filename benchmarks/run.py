@@ -26,6 +26,8 @@ def main() -> None:
                     choices=["sequential", "spmd"],
                     help="federated execution backend for fig3/fig4/table1")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     only = set(args.only.split(",")) if args.only else set(MODULES)
     if args.backend:
         from benchmarks import common

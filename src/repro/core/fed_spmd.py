@@ -307,16 +307,37 @@ def make_local_update(model: Model, fed: FedConfig,
     return local_update
 
 
+def client_program(fn, in_axes, mesh=None):
+    """jit(vmap(fn)) over a leading client axis (``in_axes`` as vmap's:
+    0 for client-stacked arguments, None for shared ones; every output
+    is client-stacked).  With ``mesh``, each device runs the vmapped
+    program on its own slice of the client stack (shard_map over the
+    mesh's client axes): the compiler cannot partition a Pallas TPU
+    kernel, and per-client work needs no communication anyway.  (No
+    varying-axis check: a Pallas call declares no varying axes for its
+    outputs.)"""
+    mapped = jax.vmap(fn, in_axes=in_axes)
+    if mesh is not None:
+        from jax.sharding import PartitionSpec as P
+
+        from repro.launch.mesh import client_axes
+        c = P(client_axes(mesh))
+        mapped = jax.shard_map(
+            mapped, mesh=mesh, out_specs=c, check_vma=False,
+            in_specs=tuple(P() if a is None else c for a in in_axes))
+    return jax.jit(mapped)
+
+
 def make_bucket_update(model: Model, fed: FedConfig,
-                       task: str = "classification"):
+                       task: str = "classification", mesh=None):
     """jit(vmap(local_update)) WITHOUT the closing FedAvg: the building
     block for per-rank bucketing and async participation, where the
     cross-client aggregation happens on the host across buckets
     (core/heterogeneous.harmonize_buckets / core/async_agg).  One
     program object — jax recompiles per (bucket size, rank, n_steps)
-    signature and caches each variant."""
+    signature and caches each variant.  ``mesh``: see client_program."""
     local_update = make_local_update(model, fed, task)
-    return jax.jit(jax.vmap(local_update, in_axes=(None, 0, 0, 0, 0, 0)))
+    return client_program(local_update, (None, 0, 0, 0, 0, 0), mesh)
 
 
 # --------------------------------------------------------------------------- #
@@ -378,7 +399,7 @@ def make_spmd_round(model: Model, fed: FedConfig,
 # 2) KD-FedLLM stages (b1/b2/b8 batched over clients)
 # --------------------------------------------------------------------------- #
 def make_kd_spmd_fns(model: Model, fed: FedConfig,
-                     task: str = "classification"):
+                     task: str = "classification", mesh=None):
     """Batched KD-FedLLM stages, clients on the leading axis:
 
     - client_update(base, slt, sopt, batches, keys, valid): vmapped b1
@@ -389,17 +410,17 @@ def make_kd_spmd_fns(model: Model, fed: FedConfig,
       one vmapped b8 distillation step against shared global knowledge.
 
     Knowledge aggregation (b4) is ``kd.aggregate_knowledge_batched``.
+    ``mesh``: see client_program.
     """
     from repro.core.fedavg import make_fns
 
     fns = make_fns(model, fed, task)
     local_update = make_local_update(model, fed, task)
-    client_update = jax.jit(jax.vmap(
-        local_update, in_axes=(None, 0, 0, 0, 0, 0)))
-    batched_logits = jax.jit(jax.vmap(
-        fns["logits_fn"], in_axes=(None, 0, None)))
-    batched_kd_step = jax.jit(jax.vmap(
-        fns["kd_step"], in_axes=(None, 0, 0, None, None, 0)))
+    client_update = client_program(local_update, (None, 0, 0, 0, 0, 0),
+                                   mesh)
+    batched_logits = client_program(fns["logits_fn"], (None, 0, None), mesh)
+    batched_kd_step = client_program(fns["kd_step"],
+                                     (None, 0, 0, None, None, 0), mesh)
     return {"client_update": client_update,
             "batched_logits": batched_logits,
             "batched_kd_step": batched_kd_step}

@@ -44,11 +44,7 @@ def host_fold_rng(seed: int, *vals) -> np.random.Generator:
     virtual client's data shard, for instance) is bit-stable no matter
     which order — or how many times — entities are materialized."""
     key = fold_chain(jax.random.PRNGKey(int(seed)), *(int(v) for v in vals))
-    try:
-        data = jax.random.key_data(key)
-    except Exception:        # legacy uint32 key arrays on older jax
-        data = key
-    words = np.asarray(data, dtype=np.uint32).ravel().tolist()
+    words = np.asarray(jax.random.key_data(key), dtype=np.uint32).ravel().tolist()
     return np.random.default_rng(words)
 
 

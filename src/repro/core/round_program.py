@@ -375,26 +375,49 @@ class SpmdExecutor:
     streaming = False
 
     def __init__(self, ctx: RoundContext, mesh=None):
+        from repro.launch.mesh import auto_axes
         self.ctx = ctx
-        self.mesh = mesh
+        self.mesh = None if mesh is None else auto_axes(mesh)
         self._bucket_update = None
         self._kfns = None
         self._seg_step = None
 
-    # -- mesh placement of the stacked client axis ---------------------- #
+    # -- mesh placement ------------------------------------------------- #
+    # With a mesh, every operand of a stacked program lives on it: the
+    # client-stacked ones sharded on the client axis, the shared ones
+    # (base weights, public batches, the global teacher) replicated, so
+    # a jitted call never mixes one-device and mesh-placed arguments.
+    # What a stacked program returns goes back to the default device
+    # before it leaves the executor: the server's programs (aggregation,
+    # evaluation, upload compression) run there, and a Pallas TPU kernel
+    # fed mesh-placed operands would need a partitioning it cannot have.
     def _shard(self, *trees):
+        if self.mesh is not None:
+            from repro.launch.sharding import shard_client_tree
+            trees = tuple(shard_client_tree(self.mesh, t) for t in trees)
+        return trees if len(trees) > 1 else trees[0]
+
+    def _shared(self, tree):
         if self.mesh is None:
-            return trees if len(trees) > 1 else trees[0]
-        from repro.launch.sharding import shard_client_tree
-        out = tuple(shard_client_tree(self.mesh, t) for t in trees)
-        return out if len(out) > 1 else out[0]
+            return tree
+        from repro.launch.sharding import replicate_tree
+        return replicate_tree(self.mesh, tree)
+
+    def _home(self, *trees):
+        if self.mesh is not None:
+            trees = jax.device_put(trees, jax.devices()[0])
+        return trees if len(trees) > 1 else trees[0]
+
+    @functools.cached_property
+    def _base(self):
+        return self._shared(self.ctx.base)
 
     # -- FedLLM a2 ------------------------------------------------------ #
     def train(self, program, jobs, rnd):
         ctx, fed, fns = self.ctx, self.ctx.fed, self.ctx.fns
         if self._bucket_update is None:
             self._bucket_update = fed_spmd.make_bucket_update(
-                ctx.model, fed, ctx.task)
+                ctx.model, fed, ctx.task, mesh=self.mesh)
         by_ci = dict(jobs)
         seeds = [fed.seed * program.epoch_seed_mult + rnd + ep
                  for ep in range(fed.local_epochs)]
@@ -406,13 +429,13 @@ class SpmdExecutor:
             batches, valid, n_tok = fed_spmd.stack_client_batches(
                 [ctx.clients_data[ci] for ci in cis], ctx.batch_size, seeds)
             keys = rng_mod.grid_keys(fed, rnd, cis, valid.shape[1])
-            stacked_lt, stacked_opt, batches, keys = self._shard(
-                stacked_lt, stacked_opt, batches, keys)
-            new_lt, _, _ = self._bucket_update(ctx.base, stacked_lt,
+            stacked_lt, stacked_opt, batches, keys, valid_m = self._shard(
+                stacked_lt, stacked_opt, batches, keys, jnp.asarray(valid))
+            new_lt, _, _ = self._bucket_update(self._base, stacked_lt,
                                                stacked_opt, batches, keys,
-                                               jnp.asarray(valid))
+                                               valid_m)
             for k, (ci, t) in enumerate(
-                    zip(cis, fed_spmd.unstack_tree(new_lt))):
+                    zip(cis, fed_spmd.unstack_tree(self._home(new_lt)))):
                 results[ci] = (t, n_tok[k])
         return [results[ci] for ci, _ in jobs]
 
@@ -420,7 +443,8 @@ class SpmdExecutor:
     def kd_train_and_logits(self, program, cis, rnd):
         ctx, fed = self.ctx, self.ctx.fed
         if self._kfns is None:
-            self._kfns = fed_spmd.make_kd_spmd_fns(ctx.model, fed, ctx.task)
+            self._kfns = fed_spmd.make_kd_spmd_fns(ctx.model, fed, ctx.task,
+                                                   mesh=self.mesh)
         kfns, lts, opts = self._kfns, program.lts, program.opts
         seeds = [fed.seed * program.epoch_seed_mult + rnd + ep
                  for ep in range(fed.local_epochs)]
@@ -432,11 +456,11 @@ class SpmdExecutor:
                 [ctx.clients_data[ci] for ci in bcis], ctx.batch_size,
                 seeds)
             keys = rng_mod.grid_keys(fed, rnd, bcis, valid.shape[1])
-            sl, so, batches, keys = self._shard(sl, so, batches, keys)
-            sl, so, _ = kfns["client_update"](ctx.base, sl, so, batches,
-                                              keys, jnp.asarray(valid))
-            logits = _batched_public_logits(kfns, ctx.base, sl, ctx.public,
-                                            ctx.eval_batch)
+            sl, so, batches, keys, valid_m = self._shard(
+                sl, so, batches, keys, jnp.asarray(valid))
+            sl, so, _ = kfns["client_update"](self._base, sl, so, batches,
+                                              keys, valid_m)
+            sl, so, logits = self._home(sl, so, self._public_logits(sl))
             for k, (ci, lt, opt) in enumerate(zip(
                     bcis, fed_spmd.unstack_tree(sl),
                     fed_spmd.unstack_tree(so))):
@@ -446,17 +470,53 @@ class SpmdExecutor:
 
     # -- KD b8 ---------------------------------------------------------- #
     def kd_distill(self, program, cis, glob, rnd):
-        ctx, fed = self.ctx, self.ctx.fed
-        kfns, lts, opts = self._kfns, program.lts, program.opts
-        for rank, bcis in fed_spmd.rank_buckets(ctx.ranks, cis):
+        lts, opts = program.lts, program.opts
+        for rank, bcis in fed_spmd.rank_buckets(self.ctx.ranks, cis):
             sl = fed_spmd.stack_trees([lts[ci] for ci in bcis])
             so = fed_spmd.stack_trees([opts[ci] for ci in bcis])
-            sl, so = self._shard(sl, so)
-            sl, so = _batched_distill(kfns, ctx.base, sl, so, ctx.public,
-                                      glob, fed, ctx.eval_batch, rnd, bcis)
+            sl, so = self._home(*self._distill(*self._shard(sl, so), glob,
+                                               rnd, bcis))
             for ci, lt, opt in zip(bcis, fed_spmd.unstack_tree(sl),
                                    fed_spmd.unstack_tree(so)):
                 lts[ci], opts[ci] = lt, opt
+
+    # -- KD b2/b6 and b8 over a client stack ---------------------------- #
+    def _public_logits(self, stacked_lt):
+        """b2/b6 for every client at once — same batch order and
+        original-row-order scatter as kd.client_logits, giving (C, N, D)
+        with row i holding public sample i's logits."""
+        ctx = self.ctx
+        outs = []
+        for batch in epoch_batches(ctx.public, ctx.eval_batch, seed=0,
+                                   drop_remainder=False):
+            outs.append(self._kfns["batched_logits"](
+                self._base, stacked_lt, self._shared(_to_jax(batch))))
+        stacked = jnp.concatenate(outs, axis=1)
+        perm = jnp.asarray(kd_mod._epoch_perm(len(ctx.public["tokens"]), 0))
+        return jnp.zeros_like(stacked).at[:, perm].set(stacked)
+
+    def _distill(self, stacked_lt, stacked_opt, teacher, rnd, client_ids):
+        """b8 for every client in a (bucket-)stack at once; per-client
+        RNG streams match the sequential executor's
+        PRNGKey(seed + 31r + ci)."""
+        ctx, fed = self.ctx, self.ctx.fed
+        rngs = self._shard(jnp.stack(
+            [jax.random.PRNGKey(fed.seed + 31 * rnd + ci)
+             for ci in client_ids]))
+        n = len(ctx.public["tokens"])
+        for ep in range(fed.kd_epochs):
+            perm = kd_mod._epoch_perm(n, ep)
+            start = 0
+            for batch in epoch_batches(ctx.public, ctx.eval_batch, seed=ep,
+                                       drop_remainder=False):
+                sel = perm[start:start + len(batch["tokens"])]
+                start += len(batch["tokens"])
+                jb, t = self._shared((_to_jax(batch),
+                                      jnp.asarray(teacher[sel])))
+                rngs, subs = fed_spmd.split_each(rngs)
+                stacked_lt, stacked_opt, _ = self._kfns["batched_kd_step"](
+                    self._base, stacked_lt, stacked_opt, jb, t, subs)
+        return stacked_lt, stacked_opt
 
     # -- Split segments (server carry threads segment-after-segment) ---- #
     def split_train(self, program, jobs, rnd):
@@ -488,42 +548,6 @@ class SpmdExecutor:
                     zip(cis, fed_spmd.unstack_tree(stacked_c))):
                 results[ci] = (t, n_tok[k], int(valid[k].sum()), shape)
         return [results[ci] for ci, _ in jobs]
-
-
-def _batched_public_logits(kfns, base, stacked_lt, public, batch_size):
-    """b2/b6 for every client at once — same batch order and original-
-    row-order scatter as kd.client_logits, giving (C, N, D) with row i
-    holding public sample i's logits."""
-    outs = []
-    for batch in epoch_batches(public, batch_size, seed=0,
-                               drop_remainder=False):
-        jb = {k: jnp.asarray(v) for k, v in batch.items()}
-        outs.append(kfns["batched_logits"](base, stacked_lt, jb))
-    stacked = jnp.concatenate(outs, axis=1)
-    perm = jnp.asarray(kd_mod._epoch_perm(len(public["tokens"]), 0))
-    return jnp.zeros_like(stacked).at[:, perm].set(stacked)
-
-
-def _batched_distill(kfns, base, stacked_lt, stacked_opt, public, teacher,
-                     fed, batch_size, rnd, client_ids):
-    """b8 for every client in a (bucket-)stack at once; per-client RNG
-    streams match the sequential executor's PRNGKey(seed + 31r + ci)."""
-    rngs = jnp.stack([jax.random.PRNGKey(fed.seed + 31 * rnd + ci)
-                      for ci in client_ids])
-    n = len(public["tokens"])
-    for ep in range(fed.kd_epochs):
-        perm = kd_mod._epoch_perm(n, ep)
-        start = 0
-        for batch in epoch_batches(public, batch_size, seed=ep,
-                                   drop_remainder=False):
-            sel = perm[start:start + len(batch["tokens"])]
-            start += len(batch["tokens"])
-            jb = {k: jnp.asarray(v) for k, v in batch.items()}
-            t = jnp.asarray(teacher[sel])
-            rngs, subs = fed_spmd.split_each(rngs)
-            stacked_lt, stacked_opt, _ = kfns["batched_kd_step"](
-                base, stacked_lt, stacked_opt, jb, t, subs)
-    return stacked_lt, stacked_opt
 
 
 class CohortStreamingExecutor(SpmdExecutor):

@@ -152,8 +152,18 @@ def kd_loss(teacher, student, temperature: float = 1.0, mask=None,
     if pad:
         t = jnp.pad(t, ((0, pad), (0, 0)))
         s = jnp.pad(s, ((0, pad), (0, 0)))
+    bvv = fit_block(V, bv)
+    if bvv != V and bvv % 128:
+        # Mosaic takes a lane block only when it is 128-aligned or the
+        # whole dim (V = 50257 would give 1733): pad the vocab to a
+        # multiple of 128 with columns that carry no probability mass
+        # in either distribution (exp(-1e30 - m) == 0, and t - s == 0).
+        vp = ((0, 0), (0, (-V) % 128))
+        t = jnp.pad(t, vp, constant_values=_kd.NEG_INF)
+        s = jnp.pad(s, vp, constant_values=_kd.NEG_INF)
+        bvv = fit_block(t.shape[1], bv)
     rows = _kd.kd_loss_rows(t, s, temperature=temperature, br=brr,
-                            bv=fit_block(V, bv), interpret=INTERPRET)[:R, 0]
+                            bv=bvv, interpret=INTERPRET)[:R, 0]
     if mask is not None:
         m = mask.reshape(-1).astype(jnp.float32)
         return jnp.sum(rows * m) / jnp.maximum(jnp.sum(m), 1.0)
@@ -179,14 +189,26 @@ def rwkv6(r, k, v, logw, u, bt: int = 64):
             Sf.reshape(B, H, D, D))
 
 
+def _row_blocks(xf, br: int):
+    """Row block for a (R, C) row kernel and ``xf`` zero-padded to a
+    multiple of it: the whole R when R <= ``br``, else ``br`` (a
+    multiple of 8, the sublane tile Mosaic needs).  Zero rows quantize
+    to zeros and are sliced off by the caller."""
+    R = xf.shape[0]
+    br = min(br, R)
+    pad = (-R) % br
+    if pad:
+        xf = jnp.pad(xf, ((0, pad), (0, 0)))
+    return xf, br
+
+
 def quantize(x, bits: int = 8, br: int = 8):
     """x: (..., C) -> (q int8, scale fp32 (..., 1))."""
     *lead, C = x.shape
     R = math.prod(lead)
-    xf = x.reshape(R, C)
-    q, sc = _q.quantize_rows(xf, bits=bits, br=fit_block(R, br, align=1),
-                             interpret=INTERPRET)
-    return q.reshape(*lead, C), sc.reshape(*lead, 1)
+    xf, br = _row_blocks(x.reshape(R, C), br)
+    q, sc = _q.quantize_rows(xf, bits=bits, br=br, interpret=INTERPRET)
+    return q[:R].reshape(*lead, C), sc[:R].reshape(*lead, 1)
 
 
 def quantize_pack4(x, br: int = 8):
@@ -198,9 +220,9 @@ def quantize_pack4(x, br: int = 8):
     xf = x.reshape(R, C)
     if C % 2:
         xf = jnp.pad(xf, ((0, 0), (0, 1)))
-    q, sc = _q.quantize_pack4_rows(xf, br=fit_block(R, br, align=1),
-                                   interpret=INTERPRET)
-    return q.reshape(*lead, (C + 1) // 2), sc.reshape(*lead, 1)
+    xf, br = _row_blocks(xf, br)
+    q, sc = _q.quantize_pack4_rows(xf, br=br, interpret=INTERPRET)
+    return q[:R].reshape(*lead, (C + 1) // 2), sc[:R].reshape(*lead, 1)
 
 
 def clip_mean_rows(g, clip: float, block_p: int = 2048):
@@ -231,9 +253,8 @@ def topk_quantize(x, k: int, bits: int = 8, br: int = 8):
         return _ref.topk_quantize_rows_ref(x, k, bits)
     *lead, V = x.shape
     R = math.prod(lead)
-    xf = x.reshape(R, V)
-    q, idx, sc = _q.topk_quantize_rows(xf, k=k, bits=bits,
-                                       br=fit_block(R, br, align=1),
+    xf, br = _row_blocks(x.reshape(R, V), br)
+    q, idx, sc = _q.topk_quantize_rows(xf, k=k, bits=bits, br=br,
                                        interpret=INTERPRET)
-    return (q.reshape(*lead, k), idx.reshape(*lead, k),
-            sc.reshape(*lead, 1))
+    return (q[:R].reshape(*lead, k), idx[:R].reshape(*lead, k),
+            sc[:R].reshape(*lead, 1))

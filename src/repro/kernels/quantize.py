@@ -54,15 +54,18 @@ def quantize_rows(x, *, bits: int = 8, br: int = 8, interpret: bool = True):
 # --------------------------------------------------------------------------- #
 # int4 with in-kernel nibble packing
 # --------------------------------------------------------------------------- #
-def _pack4_kernel(x_ref, q_ref, s_ref):
-    x = x_ref[...].astype(jnp.float32)                    # (br, C)
-    absmax = jnp.max(jnp.abs(x), axis=-1, keepdims=True)
+def _pack4_kernel(xe_ref, xo_ref, q_ref, s_ref):
+    xe = xe_ref[...].astype(jnp.float32)                  # (br, C/2) even
+    xo = xo_ref[...].astype(jnp.float32)                  # (br, C/2) odd
+    absmax = jnp.maximum(jnp.max(jnp.abs(xe), axis=-1, keepdims=True),
+                         jnp.max(jnp.abs(xo), axis=-1, keepdims=True))
     scale = jnp.maximum(absmax / 7.0, 1e-12)
-    q = jnp.clip(jnp.round(x / scale), -7.0, 7.0).astype(jnp.int32)
-    u = q & 0xF                                           # two's-comp nibble
-    br, C = u.shape
-    pair = u.reshape(br, C // 2, 2)
-    q_ref[...] = (pair[:, :, 0] | (pair[:, :, 1] << 4)).astype(jnp.uint8)
+
+    def nibble(x):                                        # two's-comp nibble
+        return jnp.clip(jnp.round(x / scale), -7.0, 7.0).astype(jnp.int32) \
+            & 0xF
+
+    q_ref[...] = (nibble(xe) | (nibble(xo) << 4)).astype(jnp.uint8)
     s_ref[...] = scale.astype(jnp.float32)
 
 
@@ -71,21 +74,23 @@ def quantize_pack4_rows(x, *, br: int = 8, interpret: bool = True):
     """x: (R, C), C even -> (packed uint8 (R, C//2), scale fp32 (R, 1)).
 
     Two int4 values per byte: even column in the low nibble, odd column
-    in the high nibble — the exact transmittable Split-FedLLM payload."""
+    in the high nibble — the exact transmittable Split-FedLLM payload.
+    The kernel reads the even and odd columns as two lane-dense arrays
+    (a strided slice outside the kernel), so packing is elementwise."""
     R, C = x.shape
     assert C % 2 == 0, C
     br = min(br, R)
     assert R % br == 0
+    half = pl.BlockSpec((br, C // 2), lambda i: (i, 0))
     return pl.pallas_call(
         _pack4_kernel,
         grid=(R // br,),
-        in_specs=[pl.BlockSpec((br, C), lambda i: (i, 0))],
-        out_specs=[pl.BlockSpec((br, C // 2), lambda i: (i, 0)),
-                   pl.BlockSpec((br, 1), lambda i: (i, 0))],
+        in_specs=[half, half],
+        out_specs=[half, pl.BlockSpec((br, 1), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((R, C // 2), jnp.uint8),
                    jax.ShapeDtypeStruct((R, 1), jnp.float32)],
         interpret=interpret,
-    )(x)
+    )(x[:, 0::2], x[:, 1::2])
 
 
 # --------------------------------------------------------------------------- #
@@ -95,14 +100,15 @@ def _topk_kernel(x_ref, v_ref, i_ref, s_ref, *, k: int, qmax: float):
     x = x_ref[...].astype(jnp.float32)                    # (br, C)
     br, C = x.shape
     col = jax.lax.broadcasted_iota(jnp.int32, (br, C), 1)
+    slot = jax.lax.broadcasted_iota(jnp.int32, (br, k), 1)
 
     def body(t, carry):
         xc, vals, idxs = carry
         m = jnp.max(xc, axis=-1, keepdims=True)           # (br, 1)
         idx = jnp.min(jnp.where(xc == m, col, C), axis=-1,
                       keepdims=True)                      # first argmax
-        vals = jax.lax.dynamic_update_slice(vals, m, (0, t))
-        idxs = jax.lax.dynamic_update_slice(idxs, idx, (0, t))
+        vals = jnp.where(slot == t, m, vals)              # write slot t
+        idxs = jnp.where(slot == t, idx, idxs)
         xc = jnp.where(col == idx, NEG_INF, xc)
         return xc, vals, idxs
 

@@ -3,52 +3,35 @@
 Functions, not module-level constants — importing this module never
 touches jax device state.  The single-pod mesh is 16x16 = 256 chips
 (v5e pod); multi-pod adds a leading ``pod`` axis (2 pods = 512 chips).
-
-Compat: ``AxisType`` / ``jax.set_mesh`` only exist on newer jax; on
-older releases we fall back to plain meshes and the ``Mesh`` context
-manager so the launch layer keeps importing and compiling everywhere.
 """
 from __future__ import annotations
 
-import contextlib
-
 import jax
-
-try:  # jax >= 0.5: explicit/auto axis types
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+
+
+def auto_axes(mesh) -> Mesh:
+    """``mesh`` with every axis in Auto mode.  ``jax.make_mesh`` makes
+    Explicit axes by default, under which every gather and vmap of the
+    model code would need a per-op output sharding; in Auto mode arrays
+    carry their NamedShardings and the compiler propagates them."""
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
 
 
 def activate_mesh(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh.
-
-    ``jax.set_mesh`` on new jax; the ``Mesh`` context manager (same
-    named-axis resolution for jit/shard_map) on older releases.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    if hasattr(mesh, "__enter__"):
-        return mesh
-    return contextlib.nullcontext(mesh)  # pragma: no cover
+    """Context manager installing ``mesh`` as the ambient mesh."""
+    return jax.set_mesh(mesh)
 
 
 def cost_analysis_dict(compiled) -> dict:
-    """``compiled.cost_analysis()`` returns a dict on new jax and a
-    one-element list of dicts on older releases — normalize to a dict."""
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)
+    return dict(compiled.cost_analysis() or {})
 
 
 def data_axes(mesh) -> tuple:

@@ -55,14 +55,23 @@ def client_shardings(mesh, tree):
 
 def shard_client_tree(mesh, tree):
     """Place a client-stacked tree with explicit client-axis
-    NamedShardings; no-op when the stack size does not divide the
-    client-axis extent (e.g. a small rank bucket), so callers can apply
-    it unconditionally."""
+    NamedShardings.  Every leaf's stack size must be a multiple of the
+    client-axis extent: a stack left off the mesh would meet mesh-placed
+    operands in the same jitted call, so a mismatch is an error, never a
+    skip."""
     from repro.launch.mesh import client_axis_size
-    leaves = jax.tree.leaves(tree)
-    if not leaves or leaves[0].shape[0] % max(client_axis_size(mesh), 1):
-        return tree
+    n = client_axis_size(mesh)
+    bad = {x.shape[0] for x in jax.tree.leaves(tree) if x.shape[0] % n}
+    if bad:
+        raise ValueError(f"client stack of {sorted(bad)} is not a multiple "
+                         f"of the mesh's client axis ({n} devices)")
     return jax.device_put(tree, client_shardings(mesh, tree))
+
+
+def replicate_tree(mesh, tree):
+    """Place a tree every client shares (base weights, public batches)
+    whole on every device of ``mesh``."""
+    return jax.device_put(tree, NamedSharding(mesh, P()))
 
 
 class ShardingPolicy:

@@ -18,6 +18,7 @@ from repro.configs.base import FedConfig
 from repro.configs.registry import ARCHS, get_config
 from repro.core.fedavg import make_fns
 from repro.data import synthetic
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.factory import build_model
 from repro.peft import lora as lora_lib
 
@@ -35,6 +36,7 @@ def main():
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if not args.full_size and not args.arch.startswith("gpt2"):

@@ -72,18 +72,9 @@ def moe_fwd(params, cfg: ModelConfig, x):
 
 
 def _ambient_mesh():
-    """The installed mesh (jax.set_mesh / ``with mesh:``), or None.
-    ``get_abstract_mesh`` only exists on newer jax; older releases track
-    the context-manager mesh in thread resources."""
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is not None:
-        return get()
-    try:
-        from jax._src import mesh as mesh_lib
-        m = mesh_lib.thread_resources.env.physical_mesh
-        return None if m.empty else m
-    except Exception:  # pragma: no cover - jax internals moved
-        return None
+    """The installed mesh (``jax.set_mesh``), or None."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 def _route_and_pack(params, cfg: ModelConfig, x):

@@ -1,6 +1,7 @@
 """shard_map MoE dispatch: multi-device equivalence vs the batched/global
 paths (run in a subprocess so the forced device count never leaks into
 other tests), plus the enc-dec Split-FedLLM boundary."""
+import pathlib
 import subprocess
 import sys
 import textwrap
@@ -48,7 +49,8 @@ SUBPROC = textwrap.dedent("""
 
 @pytest.mark.slow
 def test_shard_map_moe_multidevice_equivalence():
-    out = subprocess.run([sys.executable, "-c", SUBPROC], cwd="/root/repo",
+    root = pathlib.Path(__file__).resolve().parents[1]
+    out = subprocess.run([sys.executable, "-c", SUBPROC], cwd=root,
                          capture_output=True, text=True, timeout=480)
     assert "SHARDMAP_EQUIV_OK" in out.stdout, out.stderr[-2000:]
 

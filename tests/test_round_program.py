@@ -1,6 +1,11 @@
 """Unit tests for the composable round pipeline (core/round_program.py):
 schedule semantics, subsampling-rate reporting, and the mesh-sharded
 SPMD executor path (client-axis NamedShardings from launch/sharding)."""
+import pathlib
+import subprocess
+import sys
+import textwrap
+
 import jax
 import numpy as np
 import pytest
@@ -143,6 +148,65 @@ def test_spmd_runtime_with_mesh_matches_unsharded(small_case, framework):
                     jax.tree.leaves(sharded.final_lora)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=1e-6, rtol=1e-5)
+
+
+MESH4_SUBPROC = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import sys; sys.path.insert(0, "src"); sys.path.insert(0, "tests")
+    import jax, numpy as np
+    from test_round_program import CFG, _fed
+    from repro.core.rounds import run_federated
+    from repro.data import banking77, partition
+    from repro.launch import sharding
+
+    placed = []
+    orig = sharding.shard_client_tree
+    def spy(mesh, tree):
+        out = orig(mesh, tree)
+        placed.extend(jax.tree.leaves(out))
+        return out
+    sharding.shard_client_tree = spy
+
+    pub = banking77.generate(24, CFG.vocab_size, 12, seed=0)
+    tr = banking77.generate(128, CFG.vocab_size, 12, seed=1)
+    te = banking77.generate(16, CFG.vocab_size, 12, seed=2)
+    clients = partition.iid_partition(tr, 4, seed=0)
+    mesh = jax.make_mesh((4, 1), ("data", "model"))
+    for fw in ("fedllm", "kd"):
+        fed = _fed(framework=fw, backend="spmd", n_clients=4, rounds=2)
+        plain = run_federated(CFG, fed, pub, clients, te, batch_size=8,
+                              eval_batch=8)
+        placed.clear()
+        sharded = run_federated(CFG, fed, pub, clients, te, batch_size=8,
+                                eval_batch=8, mesh=mesh)
+        assert placed and all(len(x.sharding.device_set) == 4
+                              for x in placed), fw
+        # the server's state comes back to one device: its programs
+        # (evaluation, aggregation) must never see mesh-placed operands
+        assert all(len(x.sharding.device_set) == 1
+                   for x in jax.tree.leaves(sharded.final_lora)), fw
+        assert plain.ledger.by_name() == sharded.ledger.by_name(), fw
+        assert plain.ledger.per_client_round() == \\
+            sharded.ledger.per_client_round(), fw
+        for hp, hs in zip(plain.history, sharded.history):
+            assert abs(hp.loss - hs.loss) <= 1e-5, (fw, hp.loss, hs.loss)
+        for a, b in zip(jax.tree.leaves(plain.final_lora),
+                        jax.tree.leaves(sharded.final_lora)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=1e-6, rtol=1e-5)
+    print("MESH4_PARITY_OK")
+""")
+
+
+def test_spmd_runtime_on_four_device_mesh_matches_unsharded():
+    """The client axis sharded over 4 (virtual) devices — every stacked
+    leaf spans all four — reproduces the unsharded run.  A subprocess
+    keeps the forced device count out of the other tests."""
+    root = pathlib.Path(__file__).resolve().parents[1]
+    out = subprocess.run([sys.executable, "-c", MESH4_SUBPROC], cwd=root,
+                         capture_output=True, text=True, timeout=480)
+    assert "MESH4_PARITY_OK" in out.stdout, out.stderr[-3000:]
 
 
 def test_adapters_contain_no_per_driver_threading():
