@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.configs.base import FedConfig
 from repro.data.loader import epoch_batches
 from repro.models.factory import Model
@@ -55,7 +56,8 @@ def stack_for_clients(tree, n_clients: int):
 
 def stack_trees(trees: Sequence):
     """Stack identically-structured per-client trees on a new axis 0."""
-    return jax.tree.map(lambda *xs: jnp.stack(xs), *trees)
+    with obs.span("fl.exec.stack_trees"):
+        return jax.tree.map(lambda *xs: jnp.stack(xs), *trees)
 
 
 def split_keys(key, n_clients: int, n_steps: int):
@@ -86,28 +88,34 @@ def stack_client_batches(clients_data: List[Dict], batch_size: int,
     (C, S, B, ...) jnp arrays, ``valid`` a (C, S) bool ndarray, and
     ``n_tok`` the per-client real token counts for the cost model.
     """
-    per_client = []
-    for data in clients_data:
-        client_batches = []
-        for seed in seeds:
-            client_batches.extend(epoch_batches(data, batch_size, seed=seed))
-        per_client.append(client_batches)
-    n_steps = [len(b) for b in per_client]
-    if min(n_steps) == 0:
-        raise ValueError(
-            "spmd backend: every client needs at least one full batch "
-            f"(batch_size={batch_size}, client sizes="
-            f"{[len(d['tokens']) for d in clients_data]})")
-    n_tok = [sum(b["tokens"].size for b in bs) for bs in per_client]
-    S = max(n_steps)
-    valid = np.zeros((len(per_client), S), bool)
-    rows = []
-    for ci, bs in enumerate(per_client):
-        valid[ci, :len(bs)] = True
-        padded = bs + [bs[-1]] * (S - len(bs))
-        rows.append({k: np.stack([b[k] for b in padded]) for k in bs[0]})
-    batches = {k: jnp.asarray(np.stack([r[k] for r in rows]))
-               for k in rows[0]}
+    with obs.span("fl.exec.stack_batches"):
+        per_client = []
+        for data in clients_data:
+            client_batches = []
+            for seed in seeds:
+                client_batches.extend(
+                    epoch_batches(data, batch_size, seed=seed))
+            per_client.append(client_batches)
+        n_steps = [len(b) for b in per_client]
+        if min(n_steps) == 0:
+            raise ValueError(
+                "spmd backend: every client needs at least one full batch "
+                f"(batch_size={batch_size}, client sizes="
+                f"{[len(d['tokens']) for d in clients_data]})")
+        n_tok = [sum(b["tokens"].size for b in bs) for bs in per_client]
+        S = max(n_steps)
+        valid = np.zeros((len(per_client), S), bool)
+        rows = []
+        for ci, bs in enumerate(per_client):
+            valid[ci, :len(bs)] = True
+            padded = bs + [bs[-1]] * (S - len(bs))
+            rows.append({k: np.stack([b[k] for b in padded])
+                         for k in bs[0]})
+        batches = {k: jnp.asarray(np.stack([r[k] for r in rows]))
+                   for k in rows[0]}
+    # stacked steps past a client's own count are padding (valid=False)
+    obs.count("steps_stacked", valid.size)
+    obs.count("steps_valid", int(valid.sum()))
     return batches, valid, n_tok
 
 
@@ -115,8 +123,9 @@ def unstack_tree(stacked):
     """Inverse of ``stack_trees``: a list of per-client trees from a
     leading-axis stack (host-side seam between bucketed programs and the
     cross-bucket harmonization)."""
-    n = jax.tree.leaves(stacked)[0].shape[0]
-    return [jax.tree.map(lambda x: x[i], stacked) for i in range(n)]
+    with obs.span("fl.exec.unstack_tree"):
+        n = jax.tree.leaves(stacked)[0].shape[0]
+        return [jax.tree.map(lambda x: x[i], stacked) for i in range(n)]
 
 
 def rank_buckets(ranks: Sequence[int], clients: Sequence[int] = None):

@@ -18,6 +18,7 @@ from typing import Callable, Dict, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.configs.base import FedConfig, ModelConfig
 from repro.core import tasks
 from repro.models.factory import Model
@@ -129,8 +130,10 @@ def make_fns(model: Model, fed: FedConfig, task: str = "classification"):
                 student = tasks.class_logits(logits, batch)
             else:
                 student = logits
-            return losses.kd_kl(student, teacher_logits,
-                                fed.kd_temperature) + aux
+            with jax.named_scope("lm_head"):
+                kl = losses.kd_kl(student, teacher_logits,
+                                  fed.kd_temperature)
+            return kl + aux
 
         loss, grads = jax.value_and_grad(loss_fn)(lt)
         new_lt, new_opt = opt_update(grads, opt_state, lt, fed.lr)
@@ -166,14 +169,20 @@ def fedavg(trees: Sequence, weights: Optional[Sequence[float]] = None):
 
 
 def evaluate(fns, base, lt, data: Dict, batch_size: int = 64) -> tuple:
-    """Mean accuracy/loss over a dataset."""
-    from repro.data.loader import epoch_batches
+    """Mean accuracy/loss over a dataset: each batch is built and copied
+    to the device (``fl.eval.load``), evaluated, and its two numbers read
+    back (``fl.eval.read``) before the next is built."""
+    from repro.data.loader import epoch_batches, n_batches
     accs, losses_, n = [], [], 0
-    for batch in epoch_batches(data, batch_size, seed=0):
-        jb = {k: jnp.asarray(v) for k, v in batch.items()}
+    batches = epoch_batches(data, batch_size, seed=0)
+    for _ in range(n_batches(data, batch_size)):
+        with obs.span("fl.eval.load"):
+            batch = next(batches)
+            jb = {k: jnp.asarray(v) for k, v in batch.items()}
         a, l = fns["eval_step"](base, lt, jb)
-        accs.append(float(a) * len(batch["tokens"]))
-        losses_.append(float(l) * len(batch["tokens"]))
+        with obs.span("fl.eval.read"):
+            accs.append(float(obs.to_host(a)) * len(batch["tokens"]))
+            losses_.append(float(obs.to_host(l)) * len(batch["tokens"]))
         n += len(batch["tokens"])
     if n == 0:
         return 0.0, 0.0

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.configs.base import FedConfig
 from repro.core import compression, metrics
 from repro.data.loader import epoch_batches
@@ -142,7 +143,7 @@ def distill(fns, base, lt, opt_state, public: Dict, teacher: np.ndarray,
             rng, sub = jax.random.split(rng)
             lt, opt_state, l = fns["kd_step"](base, lt, opt_state, jb, t,
                                               sub)
-            loss += float(l) * len(batch["tokens"])
+            loss += float(obs.to_host(l)) * len(batch["tokens"])
             n += len(batch["tokens"])
     return lt, opt_state, loss / max(n, 1)
 

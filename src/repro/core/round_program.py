@@ -65,6 +65,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.configs.base import FedConfig, ModelConfig
 from repro.core import fed_spmd
 from repro.core import kd as kd_mod
@@ -97,6 +98,12 @@ class FedResult:
 
 def _to_jax(batch):
     return {k: jnp.asarray(v) for k, v in batch.items()}
+
+
+def _evaluate(ctx, lt):
+    """The server's evaluation of ``lt`` on the test set."""
+    with obs.span("fl.evaluate", round=ctx.rnd):
+        return evaluate(ctx.fns, ctx.base, lt, ctx.test, ctx.eval_batch)
 
 
 # --------------------------------------------------------------------------- #
@@ -156,6 +163,7 @@ class RoundContext:
         self.task = task
         self.batch_size, self.eval_batch = batch_size, eval_batch
         self.verbose = verbose
+        self.rnd = 0                        # the round in progress
         self.n_clients = len(self.clients_data)
         self.fns = make_fns(model, fed, task)
         self.ranks = normalize_ranks(fed.client_ranks, self.n_clients,
@@ -431,9 +439,10 @@ class SpmdExecutor:
             keys = rng_mod.grid_keys(fed, rnd, cis, valid.shape[1])
             stacked_lt, stacked_opt, batches, keys, valid_m = self._shard(
                 stacked_lt, stacked_opt, batches, keys, jnp.asarray(valid))
-            new_lt, _, _ = self._bucket_update(self._base, stacked_lt,
-                                               stacked_opt, batches, keys,
-                                               valid_m)
+            with obs.span("fl.exec.update", round=rnd, clients=len(cis)):
+                new_lt, _, _ = self._bucket_update(
+                    self._base, stacked_lt, stacked_opt, batches, keys,
+                    valid_m)
             for k, (ci, t) in enumerate(
                     zip(cis, fed_spmd.unstack_tree(self._home(new_lt)))):
                 results[ci] = (t, n_tok[k])
@@ -458,9 +467,11 @@ class SpmdExecutor:
             keys = rng_mod.grid_keys(fed, rnd, bcis, valid.shape[1])
             sl, so, batches, keys, valid_m = self._shard(
                 sl, so, batches, keys, jnp.asarray(valid))
-            sl, so, _ = kfns["client_update"](self._base, sl, so, batches,
-                                              keys, valid_m)
-            sl, so, logits = self._home(sl, so, self._public_logits(sl))
+            with obs.span("fl.exec.update", round=rnd, clients=len(bcis)):
+                sl, so, _ = kfns["client_update"](self._base, sl, so,
+                                                  batches, keys, valid_m)
+                logits = self._public_logits(sl)
+            sl, so, logits = self._home(sl, so, logits)
             for k, (ci, lt, opt) in enumerate(zip(
                     bcis, fed_spmd.unstack_tree(sl),
                     fed_spmd.unstack_tree(so))):
@@ -474,8 +485,10 @@ class SpmdExecutor:
         for rank, bcis in fed_spmd.rank_buckets(self.ctx.ranks, cis):
             sl = fed_spmd.stack_trees([lts[ci] for ci in bcis])
             so = fed_spmd.stack_trees([opts[ci] for ci in bcis])
-            sl, so = self._home(*self._distill(*self._shard(sl, so), glob,
-                                               rnd, bcis))
+            sl, so = self._shard(sl, so)
+            with obs.span("fl.exec.update", round=rnd, clients=len(bcis)):
+                sl, so = self._distill(sl, so, glob, rnd, bcis)
+            sl, so = self._home(sl, so)
             for ci, lt, opt in zip(bcis, fed_spmd.unstack_tree(sl),
                                    fed_spmd.unstack_tree(so)):
                 lts[ci], opts[ci] = lt, opt
@@ -539,10 +552,11 @@ class SpmdExecutor:
             extra = (dp_mod.noise_key_grid(fed, rnd, cis,
                                            valid.shape[1]),) if noised \
                 else ()
-            stacked_c, program.s_lt, program.s_opt, _ = self._seg_step(
-                program.base_c, program.base_s, by_ci[cis[0]],
-                program.s_lt, program.s_opt, batches, keys,
-                jnp.asarray(valid), *extra)
+            with obs.span("fl.exec.update", round=rnd, clients=len(cis)):
+                stacked_c, program.s_lt, program.s_opt, _ = self._seg_step(
+                    program.base_c, program.base_s, by_ci[cis[0]],
+                    program.s_lt, program.s_opt, batches, keys,
+                    jnp.asarray(valid), *extra)
             shape = tuple(batches["tokens"].shape[-2:])
             for k, (ci, t) in enumerate(
                     zip(cis, fed_spmd.unstack_tree(stacked_c))):
@@ -758,8 +772,7 @@ class FedLLMProgram:
         return M.tree_bytes(self.global_lt)
 
     def evaluate(self, ctx):
-        return evaluate(ctx.fns, ctx.base, self.global_lt, ctx.test,
-                        ctx.eval_batch)
+        return _evaluate(ctx, self.global_lt)
 
     def final_state(self, ctx):
         return self.global_lt
@@ -947,8 +960,7 @@ class KDProgram:
         return kd_mod.logit_wire_bytes(self.glob.shape, ctx.fed)
 
     def evaluate(self, ctx):
-        return evaluate(ctx.fns, ctx.base, self.server_lt, ctx.test,
-                        ctx.eval_batch)
+        return _evaluate(ctx, self.server_lt)
 
     def final_state(self, ctx):
         return self.server_lt
@@ -1111,8 +1123,7 @@ class SplitProgram:
         return M.tree_bytes(self.c_global)
 
     def evaluate(self, ctx):
-        return evaluate(ctx.fns, ctx.base, self.joined, ctx.test,
-                        ctx.eval_batch)
+        return _evaluate(ctx, self.joined)
 
     def final_state(self, ctx):
         return self.joined
@@ -1218,6 +1229,15 @@ def run_program(model, base, cfg: ModelConfig, fed: FedConfig, targets,
             extra = plan.extra_delay(rnd, ci) if plan is not None else 0
             schedule.submit(rnd, ci, payload, extra)
 
+    def _start(cohort, rnd):
+        """Broadcast, local update and upload for one starting cohort."""
+        with obs.span("fl.broadcast", round=rnd):
+            jobs = program.broadcast(ctx, cohort, rnd)
+        with obs.span("fl.local_update", round=rnd):
+            outs = program.local_update(ctx, ex, jobs, rnd)
+        with obs.span("fl.upload", round=rnd):
+            _submit(outs, rnd)
+
     def _screen(arrivals):
         """Validation verdicts for the whole round's arrivals at once
         (norm screen medians are round-global, so flat and streaming
@@ -1234,6 +1254,87 @@ def run_program(model, base, cfg: ModelConfig, fed: FedConfig, targets,
                           program.payload_bytes(ctx, j.payload))
         ctx.secagg.discard(ctx.secagg_start(j.start, j.client), j.client)
 
+    def _aggregate_streaming(rnd, starters):
+        """Arrivals of a streaming round -> (arrived, rolled over)."""
+        # group arrivals by masking cohort (insertion order), fold
+        # each group into the running partial aggregate and free its
+        # secagg payloads before touching the next — peak memory is
+        # one cohort of payloads plus one fp32 accumulator
+        arrivals = schedule.pop_arrivals(rnd)
+        ok = _screen(arrivals)
+        n_kept = sum(1 for j, good in zip(arrivals, ok)
+                     if good and rnd - j.start <= fed.max_staleness)
+        roll = bool(fed.quorum > 0 and starters
+                    and n_kept < fed.quorum * len(starters))
+        groups: Dict[int, List] = {}
+        for j, good in zip(arrivals, ok):
+            groups.setdefault(ctx.secagg_start(j.start, j.client),
+                              []).append((j, good))
+        state = program.agg_init(ctx)
+        arrived_cis, used_edges = [], set()
+        for gi, (gkey, gjobs) in enumerate(groups.items()):
+            kept_chunk, delivered = [], []
+            for j, good in gjobs:
+                if not good:
+                    _quarantine(j, rnd)
+                    continue
+                arrived_cis.append(j.client)
+                program.record_arrival(ctx, j, rnd)
+                s = rnd - j.start
+                if s <= fed.max_staleness:
+                    kept_chunk.append((j.client, j.payload, s,
+                                       ctx.data_w[j.client]))
+                    delivered.append((gkey, j.client))
+                else:
+                    ctx.secagg.discard(gkey, j.client)
+            ctx.secagg.deliver(ctx.ledger, rnd, delivered)
+            if not roll:
+                state = program.agg_fold(ctx, ex, state, kept_chunk, rnd)
+            used_edges.add(gi % n_edges)
+        if roll:
+            # below quorum: the cohort's payloads were received and
+            # their secure-agg masks settled, but the round rolls
+            # over — nothing folds into the global state
+            state, arrived_cis = None, []
+        program.agg_finalize(ctx, ex, state, arrived_cis, rnd)
+        if hierarchical and arrived_cis:
+            # second hop: each edge that aggregated a cohort this
+            # round forwards one fused payload up and pulls the new
+            # global down (negative ids denote edge aggregators)
+            eb = program.edge_payload_bytes(ctx)
+            for e in sorted(used_edges):
+                ctx.ledger.record(rnd, -(e + 1), "edge_agg", M.UP,
+                                  eb, hop=M.EDGE_SERVER)
+                ctx.ledger.record(rnd, -(e + 1), "edge_agg", M.DOWN,
+                                  eb, hop=M.EDGE_SERVER)
+        return len(arrived_cis), roll
+
+    def _aggregate_flat(rnd, starters):
+        """Arrivals of a flat round -> (arrived, rolled over)."""
+        arrivals = schedule.pop_arrivals(rnd)
+        ok = _screen(arrivals)
+        kept, delivered, arrived = [], [], []
+        for j, good in zip(arrivals, ok):
+            if not good:
+                _quarantine(j, rnd)
+                continue
+            arrived.append(j)
+            program.record_arrival(ctx, j, rnd)
+            s = rnd - j.start
+            if s <= fed.max_staleness:
+                kept.append((j.client, j.payload, s,
+                             ctx.data_w[j.client]))
+                delivered.append((j.start, j.client))
+            else:
+                ctx.secagg.discard(j.start, j.client)
+        ctx.secagg.deliver(ctx.ledger, rnd, delivered)
+        roll = bool(fed.quorum > 0 and starters
+                    and len(kept) < fed.quorum * len(starters))
+        if roll:
+            kept, arrived = [], []
+        program.aggregate(ctx, ex, kept, arrived, rnd)
+        return len(arrived), roll
+
     # -- crash recovery -------------------------------------------------- #
     mgr = None
     if checkpoint_every and checkpoint_every > 0:
@@ -1248,122 +1349,48 @@ def run_program(model, base, cfg: ModelConfig, fed: FedConfig, targets,
                                                     program, schedule)
 
     for rnd in range(start_rnd, fed.rounds):
-        # start cohort: free clients pull state and form this round's
-        # secure-agg masking cohort (payloads are created — and masked —
-        # now, even when they deliver rounds later)
-        starters = schedule.starters(rnd)
-        if streaming:
-            # the ready set streams through the stacked programs one
-            # cohort-sized chunk at a time; each chunk is its own
-            # secure-agg masking cohort so its payloads can be freed
-            # the moment the whole chunk delivers
-            for k, chunk in enumerate(
-                    _cohort_chunks(starters, fed.cohort_size)):
-                cid = _cohort_uid(rnd, k)
-                for ci in chunk:
-                    ctx._cohort_ids[(rnd, ci)] = cid
-                ctx.secagg.begin_cohort(ctx.ledger, rnd, chunk,
-                                        cohort_id=cid)
-                jobs = program.broadcast(ctx, chunk, rnd)
-                outs = program.local_update(ctx, ex, jobs, rnd)
-                _submit(outs, rnd)
-        else:
-            ctx.secagg.begin_cohort(ctx.ledger, rnd, starters)
-            jobs = program.broadcast(ctx, starters, rnd)
-            outs = program.local_update(ctx, ex, jobs, rnd)
-            _submit(outs, rnd)
-        # arrivals: record wire traffic, drop too-stale updates (their
-        # pairwise masks recovered like any absent cohort member's)
-        if streaming:
-            # group arrivals by masking cohort (insertion order), fold
-            # each group into the running partial aggregate and free its
-            # secagg payloads before touching the next — peak memory is
-            # one cohort of payloads plus one fp32 accumulator
-            arrivals = schedule.pop_arrivals(rnd)
-            ok = _screen(arrivals)
-            n_kept = sum(1 for j, good in zip(arrivals, ok)
-                         if good and rnd - j.start <= fed.max_staleness)
-            roll = bool(fed.quorum > 0 and starters
-                        and n_kept < fed.quorum * len(starters))
-            groups: Dict[int, List] = {}
-            for j, good in zip(arrivals, ok):
-                groups.setdefault(ctx.secagg_start(j.start, j.client),
-                                  []).append((j, good))
-            state = program.agg_init(ctx)
-            arrived_cis, used_edges = [], set()
-            for gi, (gkey, gjobs) in enumerate(groups.items()):
-                kept_chunk, delivered = [], []
-                for j, good in gjobs:
-                    if not good:
-                        _quarantine(j, rnd)
-                        continue
-                    arrived_cis.append(j.client)
-                    program.record_arrival(ctx, j, rnd)
-                    s = rnd - j.start
-                    if s <= fed.max_staleness:
-                        kept_chunk.append((j.client, j.payload, s,
-                                           ctx.data_w[j.client]))
-                        delivered.append((gkey, j.client))
-                    else:
-                        ctx.secagg.discard(gkey, j.client)
-                ctx.secagg.deliver(ctx.ledger, rnd, delivered)
-                if not roll:
-                    state = program.agg_fold(ctx, ex, state, kept_chunk,
-                                             rnd)
-                used_edges.add(gi % n_edges)
-            if roll:
-                # below quorum: the cohort's payloads were received and
-                # their secure-agg masks settled, but the round rolls
-                # over — nothing folds into the global state
-                rollovers += 1
-                state, arrived_cis = None, []
-            program.agg_finalize(ctx, ex, state, arrived_cis, rnd)
-            if hierarchical and arrived_cis:
-                # second hop: each edge that aggregated a cohort this
-                # round forwards one fused payload up and pulls the new
-                # global down (negative ids denote edge aggregators)
-                eb = program.edge_payload_bytes(ctx)
-                for e in sorted(used_edges):
-                    ctx.ledger.record(rnd, -(e + 1), "edge_agg", M.UP,
-                                      eb, hop=M.EDGE_SERVER)
-                    ctx.ledger.record(rnd, -(e + 1), "edge_agg", M.DOWN,
-                                      eb, hop=M.EDGE_SERVER)
-            arrived_n = len(arrived_cis)
-        else:
-            arrivals = schedule.pop_arrivals(rnd)
-            ok = _screen(arrivals)
-            kept, delivered, arrived = [], [], []
-            for j, good in zip(arrivals, ok):
-                if not good:
-                    _quarantine(j, rnd)
-                    continue
-                arrived.append(j)
-                program.record_arrival(ctx, j, rnd)
-                s = rnd - j.start
-                if s <= fed.max_staleness:
-                    kept.append((j.client, j.payload, s,
-                                 ctx.data_w[j.client]))
-                    delivered.append((j.start, j.client))
-                else:
-                    ctx.secagg.discard(j.start, j.client)
-            ctx.secagg.deliver(ctx.ledger, rnd, delivered)
-            if fed.quorum > 0 and starters \
-                    and len(kept) < fed.quorum * len(starters):
-                rollovers += 1
-                kept, arrived = [], []
-            program.aggregate(ctx, ex, kept, arrived, rnd)
-            arrived_n = len(arrived)
-        acc, loss = program.evaluate(ctx)
-        ctx.history.append(M.RoundMetrics(
-            rnd, acc, loss, ctx.ledger.mean_client_bytes_per_round(),
-            float(np.mean([c.flops for c in ctx.cost])) if ctx.cost else 0.0,
-            epsilon=round_epsilon(ctx.acct, max(ctx.releases, default=0))))
-        if verbose:
-            print(f"[{tag}] round {rnd}: acc={acc:.4f} loss={loss:.4f}"
-                  + (f" arrived={arrived_n}"
-                     if fed.aggregation == "async" else ""))
-        if mgr is not None and (rnd + 1) % checkpoint_every == 0:
-            from repro.checkpoint import federated as fed_ckpt
-            fed_ckpt.save_run(mgr, ctx, program, schedule, rnd, rollovers)
+        ctx.rnd = rnd
+        with obs.span("fl.round", round=rnd):
+            # start cohort: free clients pull state and form this round's
+            # secure-agg masking cohort (payloads are created — and masked
+            # — now, even when they deliver rounds later)
+            starters = schedule.starters(rnd)
+            if streaming:
+                # the ready set streams through the stacked programs one
+                # cohort-sized chunk at a time; each chunk is its own
+                # secure-agg masking cohort so its payloads can be freed
+                # the moment the whole chunk delivers
+                for k, chunk in enumerate(
+                        _cohort_chunks(starters, fed.cohort_size)):
+                    cid = _cohort_uid(rnd, k)
+                    for ci in chunk:
+                        ctx._cohort_ids[(rnd, ci)] = cid
+                    ctx.secagg.begin_cohort(ctx.ledger, rnd, chunk,
+                                            cohort_id=cid)
+                    _start(chunk, rnd)
+            else:
+                ctx.secagg.begin_cohort(ctx.ledger, rnd, starters)
+                _start(starters, rnd)
+            # arrivals: record wire traffic, drop too-stale updates (their
+            # pairwise masks recovered like any absent cohort member's)
+            with obs.span("fl.aggregate", round=rnd):
+                arrived_n, rolled = (_aggregate_streaming if streaming
+                                     else _aggregate_flat)(rnd, starters)
+            rollovers += int(rolled)
+            acc, loss = program.evaluate(ctx)
+            flops = float(np.mean([c.flops for c in ctx.cost])) \
+                if ctx.cost else 0.0
+            ctx.history.append(M.RoundMetrics(
+                rnd, acc, loss, ctx.ledger.mean_client_bytes_per_round(),
+                flops, epsilon=round_epsilon(ctx.acct,
+                                             max(ctx.releases, default=0))))
+            if verbose:
+                print(f"[{tag}] round {rnd}: acc={acc:.4f} loss={loss:.4f}"
+                      + (f" arrived={arrived_n}"
+                         if fed.aggregation == "async" else ""))
+            if mgr is not None and (rnd + 1) % checkpoint_every == 0:
+                from repro.checkpoint import federated as fed_ckpt
+                fed_ckpt.save_run(mgr, ctx, program, schedule, rnd,
+                                  rollovers)
     return FedResult(ctx.history, ctx.ledger, program.final_state(ctx),
                      [c.flops for c in ctx.cost], rollovers=rollovers)

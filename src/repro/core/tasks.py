@@ -5,6 +5,7 @@ the last non-pad position (banking77.py reserves ids [1, 78) as answer
 tokens) — matching GPT-2 classification fine-tuning in the paper."""
 from __future__ import annotations
 
+import functools
 from typing import Dict
 
 import jax
@@ -14,6 +15,17 @@ from repro.data.banking77 import N_CLASSES
 from repro.models import loss as losses
 
 
+def _head(fn):
+    """Name ``fn``'s ops ``lm_head`` in the compiled program, with the
+    head product itself (models/transformer.lm_logits)."""
+    @functools.wraps(fn)
+    def call(*args, **kwargs):
+        with jax.named_scope("lm_head"):
+            return fn(*args, **kwargs)
+    return call
+
+
+@_head
 def class_logits(logits: jax.Array, batch: Dict) -> jax.Array:
     """logits: (B, S', V) -> (B, n_classes) at the last non-pad position."""
     offset = logits.shape[1] - batch["tokens"].shape[1]   # vlm/prompt prefix
@@ -22,17 +34,20 @@ def class_logits(logits: jax.Array, batch: Dict) -> jax.Array:
     return g[:, 1:1 + N_CLASSES]
 
 
+@_head
 def classification_loss_fn(logits, batch):
     cl = class_logits(logits, batch)
     loss, _ = losses.cross_entropy(cl, batch["labels"])
     return loss, cl
 
 
+@_head
 def classification_accuracy(logits, batch) -> jax.Array:
     cl = class_logits(logits, batch)
     return losses.accuracy(cl, batch["labels"])
 
 
+@_head
 def generative_loss_fn(logits, batch):
     mask = (batch["tokens"] != 0).astype(jnp.float32)
     offset = logits.shape[1] - batch["tokens"].shape[1]

@@ -16,6 +16,8 @@ from typing import List, Sequence
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
+
 
 def float_leaves(payload) -> List:
     """The float array leaves of a payload pytree (ints — wire-byte
@@ -31,7 +33,7 @@ def float_leaves(payload) -> List:
 
 def arrays_finite(arrays: Sequence) -> bool:
     for x in arrays:
-        a = np.asarray(x).astype(np.float32, copy=False)
+        a = obs.to_host(x).astype(np.float32, copy=False)
         if not np.isfinite(a).all():
             return False
     return True
@@ -42,7 +44,7 @@ def arrays_norm(arrays: Sequence) -> float:
     screen threshold is coarse, exact dtype does not matter)."""
     total = 0.0
     for x in arrays:
-        a = np.asarray(x).astype(np.float64, copy=False)
+        a = obs.to_host(x).astype(np.float64, copy=False)
         total += float(np.square(a).sum())
     return math.sqrt(total)
 

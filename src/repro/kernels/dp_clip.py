@@ -68,6 +68,7 @@ def dp_clip_mean_rows(g, *, clip: float, bp: int = 2048,
         in_specs=[pl.BlockSpec((B, bp), lambda i: (0, i))],
         out_specs=pl.BlockSpec((B, 1), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, 1), jnp.float32),
+        name="dp_clip_norm",
         interpret=interpret,
     )(g)
     return pl.pallas_call(
@@ -77,5 +78,6 @@ def dp_clip_mean_rows(g, *, clip: float, bp: int = 2048,
                   pl.BlockSpec((B, 1), lambda i: (0, 0))],
         out_specs=pl.BlockSpec((1, bp), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, P), jnp.float32),
+        name="dp_clip_acc",
         interpret=interpret,
     )(g, norms)

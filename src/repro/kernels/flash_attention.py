@@ -128,6 +128,7 @@ def _fwd_call(q, k, v, causal: bool, window: int, q_offset: int, bq: int,
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32),
                         pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, 1), jnp.float32)],
+        name="flash_attention_fwd",
         interpret=interpret,
     )(q, k, v)
 
@@ -236,6 +237,7 @@ def _bwd_call(q, k, v, o, lse, do, causal: bool, window: int, q_offset: int,
         out_specs=pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, Sq, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
+        name="flash_attention_dq",
         interpret=interpret,
     )(q, k, v, do, lse, dd)
 
@@ -256,6 +258,7 @@ def _bwd_call(q, k, v, o, lse, do, causal: bool, window: int, q_offset: int,
                    jax.ShapeDtypeStruct((BKV, Skv, D), v.dtype)],
         scratch_shapes=[pltpu.VMEM((bkv, D), jnp.float32),
                         pltpu.VMEM((bkv, D), jnp.float32)],
+        name="flash_attention_dkv",
         interpret=interpret,
     )(q, k, v, do, lse, dd)
     return dq, dk, dv

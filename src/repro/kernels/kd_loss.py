@@ -96,6 +96,7 @@ def _fwd_call(teacher, student, temperature: float, br: int, bv: int,
                   pl.BlockSpec((br, bv), lambda i, j: (i, j))],
         out_specs=[stat_spec] * 6,
         out_shape=[stat] * 6,
+        name="kd_loss_fwd",
         interpret=interpret,
     )(teacher, student)
 
@@ -135,6 +136,7 @@ def _bwd_call(teacher, student, stats, g, temperature: float, br: int,
                    pl.BlockSpec((br, bv), lambda i, j: (i, j))],
         out_shape=[jax.ShapeDtypeStruct((R, V), teacher.dtype),
                    jax.ShapeDtypeStruct((R, V), student.dtype)],
+        name="kd_loss_bwd",
         interpret=interpret,
     )(teacher, student, *stats, g)
 

@@ -86,6 +86,7 @@ def _fwd_call(x, w, a, b, bm: int, bk: int, bn: int, interpret: bool):
                    jax.ShapeDtypeStruct((M, r), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32),
                         pltpu.VMEM((bm, r), jnp.float32)],
+        name="lora_matmul_fwd",
         interpret=interpret,
     )(x, w, a, b)
 
@@ -152,6 +153,7 @@ def _dx_call(g, w, a, b, bm: int, bk: int, bn: int, interpret: bool,
                    jax.ShapeDtypeStruct((M, r), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((bm, bk), jnp.float32),
                         pltpu.VMEM((bm, r), jnp.float32)],
+        name="lora_matmul_dx",
         interpret=interpret,
     )(g, w, a, b)
 
@@ -193,6 +195,7 @@ def _dw_call(x, g, bm: int, bk: int, bn: int, interpret: bool, w_dtype):
         out_specs=pl.BlockSpec((bk, bn), lambda i, j, t: (i, j)),
         out_shape=jax.ShapeDtypeStruct((K, N), w_dtype),
         scratch_shapes=[pltpu.VMEM((bk, bn), jnp.float32)],
+        name="lora_matmul_dw",
         interpret=interpret,
     )(x, g)
 
@@ -231,6 +234,7 @@ def _panel_grad_call(lhs, panel, bm: int, bl: int, interpret: bool,
         out_specs=pl.BlockSpec((bl, r), lambda i, t: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((L, r), out_dtype),
         scratch_shapes=[pltpu.VMEM((bl, r), jnp.float32)],
+        name="lora_matmul_panel_grad",
         interpret=interpret,
     )(lhs, panel)
 

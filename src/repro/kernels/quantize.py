@@ -47,6 +47,7 @@ def quantize_rows(x, *, bits: int = 8, br: int = 8, interpret: bool = True):
                    pl.BlockSpec((br, 1), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((R, C), jnp.int8),
                    jax.ShapeDtypeStruct((R, 1), jnp.float32)],
+        name="quantize_int8",
         interpret=interpret,
     )(x)
 
@@ -89,6 +90,7 @@ def quantize_pack4_rows(x, *, br: int = 8, interpret: bool = True):
         out_specs=[half, pl.BlockSpec((br, 1), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((R, C // 2), jnp.uint8),
                    jax.ShapeDtypeStruct((R, 1), jnp.float32)],
+        name="quantize_pack4",
         interpret=interpret,
     )(x[:, 0::2], x[:, 1::2])
 
@@ -148,5 +150,6 @@ def topk_quantize_rows(x, *, k: int, bits: int = 8, br: int = 8,
         out_shape=[jax.ShapeDtypeStruct((R, k), jnp.int8),
                    jax.ShapeDtypeStruct((R, k), jnp.int32),
                    jax.ShapeDtypeStruct((R, 1), jnp.float32)],
+        name="quantize_topk",
         interpret=interpret,
     )(x)

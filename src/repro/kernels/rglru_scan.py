@@ -68,5 +68,6 @@ def rglru_scan(a, b, h0, *, bw: int = 128, bt: int = 128,
         out_shape=[jax.ShapeDtypeStruct((B, S, W), jnp.float32),
                    jax.ShapeDtypeStruct((B, W), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((1, bw), jnp.float32)],
+        name="rglru_scan",
         interpret=interpret,
     )(a, b, h0)

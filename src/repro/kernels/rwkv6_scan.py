@@ -72,5 +72,6 @@ def rwkv6_scan(r, k, v, logw, u, *, bt: int = 64, interpret: bool = True):
         out_shape=[jax.ShapeDtypeStruct((BH, S, D), jnp.float32),
                    jax.ShapeDtypeStruct((BH, D, D), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((1, D, D), jnp.float32)],
+        name="rwkv6_scan",
         interpret=interpret,
     )(r, k, v, logw, u)

@@ -59,36 +59,47 @@ def init_block(key, cfg: ModelConfig, kind: str, cross: bool = False,
 
 def block_fwd(p, cfg: ModelConfig, kind: str, x, positions,
               enc_kv=None, causal: bool = True):
-    """Full-sequence block application.  Returns (x, aux)."""
+    """Full-sequence block application.  Returns (x, aux).  Its ops carry
+    the layer kind in their compiled name: ``attention`` (self and cross
+    attention), ``recurrence`` (RG-LRU, RWKV6 time mix) or ``mlp``
+    (dense or expert MLP, RWKV6 channel mix)."""
     aux = jnp.zeros((), jnp.float32)
-    h = common.apply_norm(cfg.norm, p["norm1"], x)
-    if kind == ATTN:
-        window = cfg.sliding_window if causal else 0
-        a = attention.attention_fwd(p["attn"], cfg, h, positions,
-                                    window=window) if causal else \
-            attention.attention_fwd_noncausal(p["attn"], cfg, h, positions)
-        x = x + a
-    elif kind == LOCAL_ATTN:
-        x = x + attention.attention_fwd(p["attn"], cfg, h, positions,
-                                        window=cfg.local_window)
-    elif kind == RGLRU:
-        out, _ = rglru.rglru_fwd(p["attn"], cfg, h)
-        x = x + out
-    elif kind == RWKV6:
-        out, _ = rwkv6.timemix_fwd(p["attn"], cfg, h)
-        x = x + out
-        h2 = common.apply_norm(cfg.norm, p["norm2"], x)
-        cm, _ = rwkv6.channelmix_fwd(p["attn"], cfg, h2)
+    mixer = "recurrence" if kind in (RGLRU, RWKV6) else "attention"
+    with jax.named_scope(mixer):
+        h = common.apply_norm(cfg.norm, p["norm1"], x)
+        if kind == ATTN:
+            window = cfg.sliding_window if causal else 0
+            a = attention.attention_fwd(p["attn"], cfg, h, positions,
+                                        window=window) if causal else \
+                attention.attention_fwd_noncausal(p["attn"], cfg, h,
+                                                  positions)
+            x = x + a
+        elif kind == LOCAL_ATTN:
+            x = x + attention.attention_fwd(p["attn"], cfg, h, positions,
+                                            window=cfg.local_window)
+        elif kind == RGLRU:
+            out, _ = rglru.rglru_fwd(p["attn"], cfg, h)
+            x = x + out
+        elif kind == RWKV6:
+            out, _ = rwkv6.timemix_fwd(p["attn"], cfg, h)
+            x = x + out
+    if kind == RWKV6:
+        with jax.named_scope("mlp"):
+            h2 = common.apply_norm(cfg.norm, p["norm2"], x)
+            cm, _ = rwkv6.channelmix_fwd(p["attn"], cfg, h2)
         return x + cm, aux
     if enc_kv is not None:
-        hx = common.apply_norm(cfg.norm, p["xnorm"], x)
-        x = x + attention.cross_attention_fwd(p["xattn"], cfg, hx, enc_kv)
-    h = common.apply_norm(cfg.norm, p["norm2"], x)
-    if cfg.is_moe:
-        m, aux = moe.moe_fwd(p["mlp"], cfg, h)
-    else:
-        m = mlp.mlp_fwd(p["mlp"], cfg, h)
-    x = x + m
+        with jax.named_scope("attention"):
+            hx = common.apply_norm(cfg.norm, p["xnorm"], x)
+            x = x + attention.cross_attention_fwd(p["xattn"], cfg, hx,
+                                                  enc_kv)
+    with jax.named_scope("mlp"):
+        h = common.apply_norm(cfg.norm, p["norm2"], x)
+        if cfg.is_moe:
+            m, aux = moe.moe_fwd(p["mlp"], cfg, h)
+        else:
+            m = mlp.mlp_fwd(p["mlp"], cfg, h)
+        x = x + m
     if "adapter" in p:                       # bottleneck adapter (PEFT)
         from repro.peft import adapters as _ad
         x = _ad.adapter_fwd(p["adapter"], x)
@@ -213,9 +224,12 @@ def embed_tokens(params, cfg: ModelConfig, tokens, img_embeds=None,
 
 
 def lm_logits(params, cfg: ModelConfig, h):
-    w = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    h = hint(h, ("pod", "data"), None, None)
-    return common.mm(h, w)
+    """The head product; its ops, and the task's reads of the logits
+    (core/tasks.py), are named ``lm_head`` in the compiled program."""
+    with jax.named_scope("lm_head"):
+        w = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        h = hint(h, ("pod", "data"), None, None)
+        return common.mm(h, w)
 
 
 # --------------------------------------------------------------------------- #
@@ -226,8 +240,9 @@ def forward(params, cfg: ModelConfig, tokens, img_embeds=None,
             remat: str = "none"):
     """Returns (logits (B,S',V), aux_loss)."""
     pat, n_groups, _ = _group_split(cfg)
-    h, positions = embed_tokens(params, cfg, tokens, img_embeds,
-                                prefix_embeds)
+    with jax.named_scope("embed"):
+        h, positions = embed_tokens(params, cfg, tokens, img_embeds,
+                                    prefix_embeds)
     h = hint(h, ("pod", "data"), None, None)
     aux0 = jnp.zeros((), jnp.float32)
 

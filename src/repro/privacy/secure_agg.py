@@ -42,6 +42,7 @@ from typing import Dict, Iterable, List, Tuple
 import jax
 import numpy as np
 
+from repro import obs
 from repro.configs.base import FedConfig
 from repro.core import metrics as M
 
@@ -54,7 +55,7 @@ _PAIR_STREAM = 0xA55A  # domain separator for pairwise mask seeds
 def flat_fixed_point(payload, frac_bits: int) -> np.ndarray:
     """Flatten a payload (pytree or array) to a fixed-point uint64
     vector: round(x * 2^frac_bits) in two's complement."""
-    leaves = [np.asarray(x, np.float64).ravel()
+    leaves = [obs.to_host(x).astype(np.float64).ravel()
               for x in jax.tree.leaves(payload)]
     flat = np.concatenate(leaves) if leaves else np.zeros(0, np.float64)
     # fault-injected NaN/Inf payloads pass through here before the

@@ -173,3 +173,7 @@ def test_fedllm_bucket_update_gpt2(compile_text, topo, mesh_devices):
                                          mesh=mesh)
     text = compile_text(update, base, *stacked)
     assert "tpu_custom_call" in text
+    # each kernel call is named <kernel>_<pass>, so a device trace can
+    # group its time by kernel
+    for name in ("lora_matmul_fwd", "flash_attention_fwd"):
+        assert name in text
