@@ -8,6 +8,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
+from repro.kernels.flash_attention import flash_attention
 
 TOL = dict(rtol=2e-3, atol=2e-4)
 
@@ -99,7 +100,7 @@ def test_kd_loss_grad_chunk_fallback_nondivisible_vocab():
 # --------------------------------------------------------------------------- #
 @pytest.mark.parametrize("causal,window", [(True, 0), (True, 32),
                                            (False, 0)])
-@pytest.mark.parametrize("H,KV", [(4, 4), (4, 2)])
+@pytest.mark.parametrize("H,KV", [(4, 4), (4, 2), (12, 3)])
 def test_attention_grad_parity(causal, window, H, KV):
     B, S, D = 2, 64, 32
     ks = jax.random.split(jax.random.PRNGKey(window + H + KV), 4)
@@ -121,3 +122,41 @@ def test_attention_grad_parity(causal, window, H, KV):
         return jnp.sum(out * probe)
 
     assert_grads_close(f_kernel, f_ref, (q, k, v), "qkv")
+
+
+@pytest.mark.parametrize("H,KV,S,blk,hb", [
+    (6, 6, 32, 16, 3),      # MHA, two q blocks: every sum in the same order
+    (6, 2, 16, 16, 6),      # GQA, one q block: two kv heads per step
+    (6, 2, 32, 16, 3),      # GQA, one kv head's whole group per step
+    (6, 1, 32, 16, 2),      # GQA, part of a group per step
+])
+def test_attention_heads_per_step_matches_one_head(H, KV, S, blk, hb):
+    """Blocking heads changes no head's arithmetic: outputs and dq equal
+    the one-head-per-step kernel bit for bit; dk/dv too unless several q
+    blocks of a GQA group meet in one accumulator in another order."""
+    B, D = 2, 32
+    ks = jax.random.split(jax.random.PRNGKey(H + KV + hb), 4)
+    q = rand(ks[0], (B * H, S, D))
+    k = rand(ks[1], (B * KV, S, D))
+    v = rand(ks[2], (B * KV, S, D))
+    probe = rand(ks[3], (B * H, S, D))
+
+    def f(q, k, v, n):
+        return jnp.sum(probe * flash_attention(
+            q, k, v, causal=True, window=24, bq=blk, bkv=blk,
+            heads_per_step=n))
+
+    np.testing.assert_array_equal(
+        np.asarray(flash_attention(q, k, v, bq=blk, bkv=blk,
+                                   heads_per_step=hb)),
+        np.asarray(flash_attention(q, k, v, bq=blk, bkv=blk,
+                                   heads_per_step=1)))
+    grads = [jax.grad(f, argnums=(0, 1, 2))(q, k, v, n) for n in (hb, 1)]
+    reordered = KV < H and S > blk and hb > 1
+    for name, a, b in zip("qkv", *grads):
+        if name == "q" or not reordered:
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                          err_msg=name)
+        else:
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-5, atol=1e-6, err_msg=name)

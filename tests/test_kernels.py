@@ -7,6 +7,7 @@ import numpy as np
 import pytest
 
 from repro.core import compression
+from repro.kernels import flash_attention as _fa
 from repro.kernels import ops, ref
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.kd_loss import kd_loss_rows
@@ -43,7 +44,9 @@ def test_lora_matmul_sweep(M, K, N, r, dtype):
 
 # --------------------------------------------------------------------------- #
 @pytest.mark.parametrize("Sq,D,H,KV", [(128, 64, 4, 4), (256, 64, 4, 2),
-                                       (256, 128, 8, 1)])
+                                       (256, 128, 8, 1),
+                                       # short blocks, many heads per step
+                                       (48, 64, 12, 12), (64, 128, 20, 4)])
 @pytest.mark.parametrize("causal,window", [(True, 0), (True, 64),
                                            (False, 0)])
 def test_flash_attention_sweep(Sq, D, H, KV, causal, window):
@@ -188,6 +191,31 @@ def test_topk_quantize_sweep(R, C, k, bits):
 def test_fit_block_aligned_divisors(n, cap, expect):
     got = ops.fit_block(n, cap)
     assert got == expect and n % got == 0
+
+
+@pytest.mark.parametrize("B,H,KV,S,D,dtype,expect", [
+    (16, 12, 12, 80, 64, jnp.float32, 16),   # GPT-2 at batch 16 x 80
+    (16, 12, 12, 80, 64, jnp.bfloat16, 16),
+    (16, 12, 2, 80, 64, jnp.float32, 24),    # GQA, G = 6: whole groups
+    (2, 12, 2, 80, 128, jnp.float32, 24),    # Qwen2-1.5B at batch 2 x 80
+    (3, 7, 7, 8, 64, jnp.float32, 21),       # B*H = 21: all, under budget
+    (1, 12, 12, 2048, 64, jnp.float32, 6),   # long: full 128 x 128 blocks
+    (1, 12, 2, 2048, 128, jnp.float32, 6),
+    (1, 8, 8, 4096, 256, jnp.float32, 4),    # wide heads: the VMEM budget
+])
+def test_pick_heads_per_step(B, H, KV, S, D, dtype, expect):
+    G = H // KV
+    blk = ops.fit_block(S, 128)
+    itemsize = jnp.dtype(dtype).itemsize
+    hb = _fa.pick_heads_per_step(B * H, G, blk, blk, D, itemsize)
+    assert hb == expect
+    assert (B * H) % hb == 0 and (hb % G == 0 or G % hb == 0)
+    assert _fa.step_vmem_bytes(hb, G, blk, blk, D, itemsize) \
+        <= _fa._STEP_VMEM_BYTES
+    # the next larger valid choice would exceed the target or the budget
+    assert hb * blk * blk >= _fa._STEP_SCORES or hb == B * H or \
+        _fa.step_vmem_bytes(2 * hb, G, blk, blk, D, itemsize) \
+        > _fa._STEP_VMEM_BYTES
 
 
 def test_kd_loss_nondivisible_vocab_streams_chunks():

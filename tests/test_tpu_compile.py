@@ -90,6 +90,19 @@ def test_flash_attention_fwd_bwd(compile_text):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("B,S,H,KV,D", [
+    (2, 80, 12, 2, 128),      # Qwen2-1.5B: GQA 12/2, many heads per step
+    (1, 2048, 12, 12, 64),    # long sequence: full blocks, one head per step
+])
+def test_flash_attention_fwd_bwd_shapes(compile_text, B, S, H, KV, D):
+    q, kv = _sds((B, S, H, D)), _sds((B, S, KV, D))
+    text = compile_text(_grad_all(ops.mha_attention, 3), q, kv, kv)
+    assert "tpu_custom_call" in text
+    for name in ("flash_attention_fwd", "flash_attention_dq",
+                 "flash_attention_dkv"):
+        assert name in text
+
+
 @pytest.mark.parametrize("V", [77, 50_257])
 def test_kd_loss_fwd_bwd(compile_text, V):
     """77 Banking77 classes, and the full GPT-2 vocabulary (not a
