@@ -100,9 +100,14 @@ def test_kd_loss_grad_chunk_fallback_nondivisible_vocab():
 # --------------------------------------------------------------------------- #
 @pytest.mark.parametrize("causal,window", [(True, 0), (True, 32),
                                            (False, 0)])
-@pytest.mark.parametrize("H,KV", [(4, 4), (4, 2), (12, 3)])
-def test_attention_grad_parity(causal, window, H, KV):
-    B, S, D = 2, 64, 32
+@pytest.mark.parametrize("H,KV,S,D,blk", [
+    pytest.param(4, 4, 64, 32, 32, id="4-4"),
+    pytest.param(4, 2, 64, 32, 32, id="4-2"),
+    pytest.param(12, 3, 64, 32, 32, id="12-3"),
+    # Qwen2-1.5B: GQA 12/2, head dim 128, 80 tokens in one block
+    pytest.param(12, 2, 80, 128, 80, id="qwen2-12-2")])
+def test_attention_grad_parity(causal, window, H, KV, S, D, blk):
+    B = 2
     ks = jax.random.split(jax.random.PRNGKey(window + H + KV), 4)
     q = rand(ks[0], (B, S, H, D))
     k = rand(ks[1], (B, S, KV, D))
@@ -111,7 +116,7 @@ def test_attention_grad_parity(causal, window, H, KV):
 
     def f_kernel(q, k, v):
         out = ops.mha_attention(q, k, v, causal=causal, window=window,
-                                bq=32, bkv=32)
+                                bq=blk, bkv=blk)
         return jnp.sum(out * probe)
 
     def f_ref(q, k, v):
@@ -124,17 +129,23 @@ def test_attention_grad_parity(causal, window, H, KV):
     assert_grads_close(f_kernel, f_ref, (q, k, v), "qkv")
 
 
-@pytest.mark.parametrize("H,KV,S,blk,hb", [
-    (6, 6, 32, 16, 3),      # MHA, two q blocks: every sum in the same order
-    (6, 2, 16, 16, 6),      # GQA, one q block: two kv heads per step
-    (6, 2, 32, 16, 3),      # GQA, one kv head's whole group per step
-    (6, 1, 32, 16, 2),      # GQA, part of a group per step
+@pytest.mark.parametrize("H,KV,S,blk,hb,D", [
+    # MHA, two q blocks: every sum in the same order
+    pytest.param(6, 6, 32, 16, 3, 32, id="6-6-32-16-3"),
+    # GQA, one q block: two kv heads per step
+    pytest.param(6, 2, 16, 16, 6, 32, id="6-2-16-16-6"),
+    # GQA, one kv head's whole group per step
+    pytest.param(6, 2, 32, 16, 3, 32, id="6-2-32-16-3"),
+    # GQA, part of a group per step
+    pytest.param(6, 1, 32, 16, 2, 32, id="6-1-32-16-2"),
+    # Qwen2-1.5B at batch 2 x 80: pick_heads_per_step's 24, four kv heads
+    pytest.param(12, 2, 80, 80, 24, 128, id="qwen2-12-2-80-80-24"),
 ])
-def test_attention_heads_per_step_matches_one_head(H, KV, S, blk, hb):
+def test_attention_heads_per_step_matches_one_head(H, KV, S, blk, hb, D):
     """Blocking heads changes no head's arithmetic: outputs and dq equal
     the one-head-per-step kernel bit for bit; dk/dv too unless several q
     blocks of a GQA group meet in one accumulator in another order."""
-    B, D = 2, 32
+    B = 2
     ks = jax.random.split(jax.random.PRNGKey(H + KV + hb), 4)
     q = rand(ks[0], (B * H, S, D))
     k = rand(ks[1], (B * KV, S, D))

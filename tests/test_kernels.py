@@ -46,7 +46,9 @@ def test_lora_matmul_sweep(M, K, N, r, dtype):
 @pytest.mark.parametrize("Sq,D,H,KV", [(128, 64, 4, 4), (256, 64, 4, 2),
                                        (256, 128, 8, 1),
                                        # short blocks, many heads per step
-                                       (48, 64, 12, 12), (64, 128, 20, 4)])
+                                       (48, 64, 12, 12), (64, 128, 20, 4),
+                                       # Qwen2-1.5B: GQA 12/2 at 80 tokens
+                                       (80, 128, 12, 2)])
 @pytest.mark.parametrize("causal,window", [(True, 0), (True, 64),
                                            (False, 0)])
 def test_flash_attention_sweep(Sq, D, H, KV, causal, window):
@@ -55,8 +57,10 @@ def test_flash_attention_sweep(Sq, D, H, KV, causal, window):
     q = rand(ks[0], (B * H, Sq, D))
     k = rand(ks[1], (B * KV, Sq, D))
     v = rand(ks[2], (B * KV, Sq, D))
+    # 48 and 80 tokens are one block, as ops.fit_block gives them
+    blk = 64 if Sq % 64 == 0 else Sq
     out = flash_attention(q, k, v, causal=causal, window=window,
-                          bq=64, bkv=64)
+                          bq=blk, bkv=blk)
     expect = ref.attention_ref(q, k, v, causal=causal, window=window)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                rtol=2e-4, atol=2e-4)
@@ -198,6 +202,7 @@ def test_fit_block_aligned_divisors(n, cap, expect):
     (16, 12, 12, 80, 64, jnp.bfloat16, 16),
     (16, 12, 2, 80, 64, jnp.float32, 24),    # GQA, G = 6: whole groups
     (2, 12, 2, 80, 128, jnp.float32, 24),    # Qwen2-1.5B at batch 2 x 80
+    (16, 12, 2, 80, 128, jnp.float32, 24),   # and its evaluation, 16 x 80
     (3, 7, 7, 8, 64, jnp.float32, 21),       # B*H = 21: all, under budget
     (1, 12, 12, 2048, 64, jnp.float32, 6),   # long: full 128 x 128 blocks
     (1, 12, 2, 2048, 128, jnp.float32, 6),

@@ -15,6 +15,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs.base import FedConfig
 from repro.configs.gpt2_small import gpt2
+from repro.configs.qwen2_1_5b import config as qwen2_1_5b
 from repro.core import fed_spmd
 from repro.core.fedavg import make_fns
 from repro.kernels import ops
@@ -22,6 +23,7 @@ from repro.models.factory import build_model
 from repro.peft import lora as lora_lib
 
 GPT2_LORA_PARAMS = 442_368     # rank-8 LoRA on wq/wk/wv, 12 layers
+V5E_BYTES_LIMIT = 16_909_336_064   # memory_stats()["bytes_limit"] of a v5e
 
 
 @pytest.fixture(scope="module")
@@ -40,12 +42,11 @@ def one_chip(topo):
 
 
 @pytest.fixture
-def compile_text(one_chip, monkeypatch):
-    """``compile_text(fn, *shapes)`` -> the compiled HLO text of
-    ``fn`` for v5e, with the kernels compiled (not interpreted) and the
-    persistent compile cache off (a TPU entry written here could not be
-    read back without a chip).  Shapes without a sharding go on one
-    chip."""
+def compile_for_chip(one_chip, monkeypatch):
+    """``compile_for_chip(fn, *shapes)`` -> ``fn`` compiled for v5e,
+    with the kernels compiled (not interpreted) and the persistent
+    compile cache off (a TPU entry written here could not be read back
+    without a chip).  Shapes without a sharding go on one chip."""
     from jax.experimental.compilation_cache import compilation_cache
     monkeypatch.setattr(ops, "INTERPRET", False)
     prev = jax.config.jax_enable_compilation_cache
@@ -57,11 +58,18 @@ def compile_text(one_chip, monkeypatch):
             lambda s: s if s.sharding is not None else jax.ShapeDtypeStruct(
                 s.shape, s.dtype, sharding=one_chip), shapes)
         with ops.policy_scope("pallas"):
-            return jax.jit(fn).lower(*specs).compile().as_text()
+            return jax.jit(fn).lower(*specs).compile()
 
     yield run
     jax.config.update("jax_enable_compilation_cache", prev)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def compile_text(compile_for_chip):
+    """``compile_text(fn, *shapes)`` -> the compiled HLO text of ``fn``
+    for v5e (``compile_for_chip``)."""
+    return lambda fn, *shapes: compile_for_chip(fn, *shapes).as_text()
 
 
 def _sds(shape, dtype=jnp.float32):
@@ -190,3 +198,23 @@ def test_fedllm_bucket_update_gpt2(compile_text, topo, mesh_devices):
     # group its time by kernel
     for name in ("lora_matmul_fwd", "flash_attention_fwd"):
         assert name in text
+
+
+def test_fedllm_bucket_update_qwen2(compile_for_chip):
+    """The stacked local-update program at Qwen2-1.5B's published
+    widths: 3 clients, 2 local steps of batch 2 x 80 tokens, LoRA rank
+    8.  Its temporaries and arguments must fit what one v5e lets a
+    program hold: a batch of 2 is what fits."""
+    import dataclasses
+    cfg = dataclasses.replace(qwen2_1_5b(), kernel_policy="pallas")
+    model = build_model(cfg)
+    fed = FedConfig(lora_rank=8, backend="spmd")
+    base, stacked = _bucket_update_args(model, fed, 3, B=2)
+    update = fed_spmd.make_bucket_update(model, fed, "classification")
+    compiled = compile_for_chip(update, base, *stacked)
+    text = compiled.as_text()
+    for name in ("tpu_custom_call", "lora_matmul_fwd", "flash_attention_fwd"):
+        assert name in text
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes \
+        < V5E_BYTES_LIMIT
