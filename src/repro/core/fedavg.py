@@ -55,6 +55,11 @@ def make_fns(model: Model, fed: FedConfig, task: str = "classification"):
 
     priv = fed.privacy
 
+    def _forward(bound, batch):
+        """(task logits (tasks.task_logits), aux loss)."""
+        h, aux = model.hidden(bound, batch)
+        return tasks.task_logits(task, model, bound, h, batch), aux
+
     def train_step_impl(base, lt, opt_state, batch, rng):
         """Raw (un-jitted) local step — also scanned/vmapped by the SPMD
         backend (core/fed_spmd.py), so both backends share ONE loss.
@@ -66,8 +71,7 @@ def make_fns(model: Model, fed: FedConfig, task: str = "classification"):
         deterministic, so the backends stay in parity for free.  The
         seeded payload noise lives at the upload boundary, not here."""
         def loss_fn(l):
-            bound = _bind(base, l, rng)
-            logits, aux = model.forward(bound, batch)
+            logits, aux = _forward(_bind(base, l, rng), batch)
             loss, _ = task_loss(logits, batch)
             return loss + aux
 
@@ -76,8 +80,7 @@ def make_fns(model: Model, fed: FedConfig, task: str = "classification"):
 
             def example_loss(l, example):
                 one = jax.tree.map(lambda x: x[None], example)
-                bound = _bind(base, l, rng)
-                logits, aux = model.forward(bound, one)
+                logits, aux = _forward(_bind(base, l, rng), one)
                 loss, _ = task_loss(logits, one)
                 return loss + aux
 
@@ -99,24 +102,17 @@ def make_fns(model: Model, fed: FedConfig, task: str = "classification"):
 
     @jax.jit
     def eval_step(base, lt, batch):
-        bound = _bind(base, lt)
-        logits, _ = model.forward(bound, batch)
-        if task == "classification":
-            acc = tasks.classification_accuracy(logits, batch)
-        else:
-            acc = -task_loss(logits, batch)[0]
+        logits, _ = _forward(_bind(base, lt), batch)
         loss, _ = task_loss(logits, batch)
-        return acc, loss
+        if task == "classification":
+            return tasks.classification_accuracy(logits, batch), loss
+        return -loss, loss
 
     @jax.jit
     def logits_fn(base, lt, batch):
         """Knowledge representation for KD (paper b2/b6): class logits for
         classification, full LM logits for generative tasks."""
-        bound = _bind(base, lt)
-        logits, _ = model.forward(bound, batch)
-        if task == "classification":
-            return tasks.class_logits(logits, batch)
-        return logits
+        return _forward(_bind(base, lt), batch)[0]
 
     @jax.jit
     def kd_step(base, lt, opt_state, batch, teacher_logits, rng):
@@ -124,12 +120,7 @@ def make_fns(model: Model, fed: FedConfig, task: str = "classification"):
         from repro.models import loss as losses
 
         def loss_fn(l):
-            bound = _bind(base, l, rng)
-            logits, aux = model.forward(bound, batch)
-            if task == "classification":
-                student = tasks.class_logits(logits, batch)
-            else:
-                student = logits
+            student, aux = _forward(_bind(base, l, rng), batch)
             with jax.named_scope("lm_head"):
                 kl = losses.kd_kl(student, teacher_logits,
                                   fed.kd_temperature)

@@ -132,8 +132,8 @@ def make_split_fns(model: Model, fed: FedConfig,
 
             def server_fwd(sl, h_in):
                 bound = _bind(base_s, sl, rng)
-                logits, aux = encdec.decode_given_enc(bound, cfg, tokens,
-                                                      h_in)
+                h, aux = encdec.decode_given_enc(bound, cfg, tokens, h_in)
+                logits = tasks.task_logits(task, model, bound, h, batch)
                 loss, _ = task_loss(logits, batch)
                 return loss + aux
         else:
@@ -157,7 +157,7 @@ def make_split_fns(model: Model, fed: FedConfig,
                     bound, cfg, h_in, positions, 0, n_groups - L,
                     include_tail=True)
                 h = common.apply_norm(cfg.norm, bound["final_norm"], h)
-                logits = transformer.lm_logits(bound, cfg, h)
+                logits = tasks.task_logits(task, model, bound, h, batch)
                 loss, _ = task_loss(logits, batch)
                 return loss + aux
 

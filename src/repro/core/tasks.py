@@ -2,7 +2,10 @@
 
 Classification-as-LM: class c's logit is the LM logit of vocab id 1+c at
 the last non-pad position (banking77.py reserves ids [1, 78) as answer
-tokens) — matching GPT-2 classification fine-tuning in the paper."""
+tokens) — matching GPT-2 classification fine-tuning in the paper.  So the
+classification head multiplies the final hidden state at that one
+position by the head's answer-id rows alone: no other vocabulary logit
+is formed, in the forward or the backward pass."""
 from __future__ import annotations
 
 import functools
@@ -13,6 +16,8 @@ import jax.numpy as jnp
 
 from repro.data.banking77 import N_CLASSES
 from repro.models import loss as losses
+
+ANSWER_ROWS = (1, 1 + N_CLASSES)    # the head's rows of the answer ids
 
 
 def _head(fn):
@@ -26,24 +31,34 @@ def _head(fn):
 
 
 @_head
-def class_logits(logits: jax.Array, batch: Dict) -> jax.Array:
-    """logits: (B, S', V) -> (B, n_classes) at the last non-pad position."""
-    offset = logits.shape[1] - batch["tokens"].shape[1]   # vlm/prompt prefix
+def class_logits(model, params, h: jax.Array, batch: Dict) -> jax.Array:
+    """h: final hidden state (B, S', d) -> (B, n_classes): the answer-id
+    rows of the head at each row's last non-pad position."""
+    offset = h.shape[1] - batch["tokens"].shape[1]        # vlm/prompt prefix
     pos = offset + batch["lengths"].astype(jnp.int32) - 1  # (B,)
-    g = jnp.take_along_axis(logits, pos[:, None, None], axis=1)[:, 0]
-    return g[:, 1:1 + N_CLASSES]
+    last = jnp.take_along_axis(h, pos[:, None, None], axis=1)[:, 0]
+    return model.head(params, last, ANSWER_ROWS)
+
+
+def task_logits(task: str, model, params, h: jax.Array, batch: Dict):
+    """The task's logits from the final hidden state (``Model.hidden``):
+    class logits (B, n_classes) for classification, the whole head's
+    (B, S', V) for the generative task."""
+    if task == "classification":
+        return class_logits(model, params, h, batch)
+    return model.head(params, h)
 
 
 @_head
-def classification_loss_fn(logits, batch):
-    cl = class_logits(logits, batch)
+def classification_loss_fn(cl, batch):
+    """cl: class logits (B, n_classes)."""
     loss, _ = losses.cross_entropy(cl, batch["labels"])
     return loss, cl
 
 
 @_head
-def classification_accuracy(logits, batch) -> jax.Array:
-    cl = class_logits(logits, batch)
+def classification_accuracy(cl, batch) -> jax.Array:
+    """cl: class logits (B, n_classes)."""
     return losses.accuracy(cl, batch["labels"])
 
 
@@ -62,5 +77,6 @@ def task_logit_dim(task: str, vocab_size: int) -> int:
 
 
 def get_loss_fn(task: str):
+    """(task logits (``task_logits``), batch) -> (loss, logits)."""
     return (classification_loss_fn if task == "classification"
             else generative_loss_fn)

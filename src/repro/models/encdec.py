@@ -70,16 +70,18 @@ def _cross_kvs(params, cfg: ModelConfig, enc_out):
     return stacked, tail
 
 
-def encdec_forward(params, cfg: ModelConfig, tokens, enc_embeds,
-                   scan_layers: bool = True):
-    """Training / scoring forward.  Returns (logits, aux)."""
+def encdec_hidden(params, cfg: ModelConfig, tokens, enc_embeds,
+                  scan_layers: bool = True):
+    """Training / scoring trunk.  Returns (final-normed decoder hidden
+    state, aux): the input of the head (transformer.lm_logits)."""
     enc_out = encode(params, cfg, enc_embeds)
     return decode_given_enc(params, cfg, tokens, enc_out)
 
 
 def decode_given_enc(params, cfg: ModelConfig, tokens, enc_out):
     """Decoder stack given precomputed encoder states (the Split-FedLLM
-    boundary for encoder-decoder archs: client=encoder, server=decoder)."""
+    boundary for encoder-decoder archs: client=encoder, server=decoder).
+    Returns (final-normed hidden state, aux)."""
     xkv_stacked, xkv_tail = _cross_kvs(params, cfg, enc_out)
     h, positions = transformer.embed_tokens(params, cfg, tokens)
     aux = jnp.zeros((), jnp.float32)
@@ -96,8 +98,7 @@ def decode_given_enc(params, cfg: ModelConfig, tokens, enc_out):
     for tp, xkv in zip(params["tail"], xkv_tail):
         h, a = transformer.block_fwd(tp, cfg, ATTN, h, positions, enc_kv=xkv)
         aux = aux + a
-    h = common.apply_norm(cfg.norm, params["final_norm"], h)
-    return transformer.lm_logits(params, cfg, h), aux
+    return common.apply_norm(cfg.norm, params["final_norm"], h), aux
 
 
 def init_encdec_cache(params, cfg: ModelConfig, batch: int, max_len: int,

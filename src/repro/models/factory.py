@@ -3,6 +3,8 @@
     model = build_model(cfg)
     params = model.init(key, dtype)
     logits, aux = model.forward(params, batch)      # train / prefill
+    h, aux = model.hidden(params, batch)            # ... before the head
+    logits = model.head(params, h[, rows])
     cache = model.init_cache(params, batch_size, max_len, batch)
     logits, cache = model.decode_step(params, cache, token, pos)
 
@@ -12,7 +14,7 @@
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -37,20 +39,35 @@ class Model:
         return jax.eval_shape(lambda k: self.init(k, dtype), key)
 
     # ------------------------------------------------------------------ #
-    def forward(self, params, batch: Dict[str, Any], scan_layers: bool = True,
-                remat: str = "none"):
+    def hidden(self, params, batch: Dict[str, Any], scan_layers: bool = True,
+               remat: str = "none"):
+        """(final-normed hidden state (B, S', d), aux loss): what the head
+        (``head``) and the task heads (core/tasks.py) read."""
         from repro.kernels import ops as kernel_ops
         with kernel_ops.policy_scope(self.cfg.kernel_policy):
             if self.cfg.is_encoder_decoder:
-                return encdec.encdec_forward(params, self.cfg,
-                                             batch["tokens"],
-                                             batch["enc_embeds"],
-                                             scan_layers=scan_layers)
-            return transformer.forward(
+                return encdec.encdec_hidden(params, self.cfg,
+                                            batch["tokens"],
+                                            batch["enc_embeds"],
+                                            scan_layers=scan_layers)
+            return transformer.hidden(
                 params, self.cfg, batch["tokens"],
                 img_embeds=batch.get("img_embeds"),
                 prefix_embeds=batch.get("prefix_embeds"),
                 scan_layers=scan_layers, remat=remat)
+
+    def head(self, params, h, rows: Optional[Tuple[int, int]] = None):
+        """h (..., d) -> logits over the whole vocabulary, or over its
+        rows ``[lo, hi)``."""
+        from repro.kernels import ops as kernel_ops
+        with kernel_ops.policy_scope(self.cfg.kernel_policy):
+            return transformer.lm_logits(params, self.cfg, h, rows)
+
+    def forward(self, params, batch: Dict[str, Any], scan_layers: bool = True,
+                remat: str = "none"):
+        """(logits (B, S', V), aux loss)."""
+        h, aux = self.hidden(params, batch, scan_layers, remat)
+        return self.head(params, h), aux
 
     # ------------------------------------------------------------------ #
     def init_cache(self, params, batch_size: int, max_len: int,

@@ -223,22 +223,45 @@ def embed_tokens(params, cfg: ModelConfig, tokens, img_embeds=None,
     return h, positions
 
 
-def lm_logits(params, cfg: ModelConfig, h):
-    """The head product; its ops, and the task's reads of the logits
-    (core/tasks.py), are named ``lm_head`` in the compiled program."""
+def _head_weight(params, cfg: ModelConfig,
+                 rows: Optional[Tuple[int, int]] = None):
+    """The (d, V) head, or its vocabulary rows ``[lo, hi)``: the tied
+    embedding transposed, or ``lm_head`` (a LoRA-bound ``lm_head`` has
+    its ``w`` and ``b`` cut alike)."""
+    if cfg.tie_embeddings:
+        e = params["embed"]
+        return (e if rows is None else e[rows[0]:rows[1]]).T
+    w = params["lm_head"]
+    if rows is None:
+        return w
+
+    def cut(x):
+        return x[:, rows[0]:rows[1]]
+    if isinstance(w, dict):
+        return {**w, "w": cut(w["w"]), "b": cut(w["b"])}
+    return cut(w)
+
+
+def lm_logits(params, cfg: ModelConfig, h,
+              rows: Optional[Tuple[int, int]] = None):
+    """h (..., d) -> logits (..., V), or (..., hi - lo) over the vocabulary
+    rows ``rows = (lo, hi)``.  Its ops, and the task's reads of the
+    hidden state (core/tasks.py), are named ``lm_head`` in the compiled
+    program."""
     with jax.named_scope("lm_head"):
-        w = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-        h = hint(h, ("pod", "data"), None, None)
+        w = _head_weight(params, cfg, rows)
+        h = hint(h, ("pod", "data"), *(None,) * (h.ndim - 1))
         return common.mm(h, w)
 
 
 # --------------------------------------------------------------------------- #
-# Full forward (train / prefill)
+# Full-sequence trunk (train / prefill)
 # --------------------------------------------------------------------------- #
-def forward(params, cfg: ModelConfig, tokens, img_embeds=None,
-            prefix_embeds=None, scan_layers: bool = True,
-            remat: str = "none"):
-    """Returns (logits (B,S',V), aux_loss)."""
+def hidden(params, cfg: ModelConfig, tokens, img_embeds=None,
+           prefix_embeds=None, scan_layers: bool = True,
+           remat: str = "none"):
+    """Returns (final-normed hidden state (B,S',d), aux_loss): the input
+    of the head (``lm_logits``)."""
     pat, n_groups, _ = _group_split(cfg)
     with jax.named_scope("embed"):
         h, positions = embed_tokens(params, cfg, tokens, img_embeds,
@@ -278,8 +301,7 @@ def forward(params, cfg: ModelConfig, tokens, img_embeds=None,
         kind = pat[ti % len(pat)]
         h, a = block_fwd(tp, cfg, kind, h, positions)
         aux = aux + a
-    h = common.apply_norm(cfg.norm, params["final_norm"], h)
-    return lm_logits(params, cfg, h), aux
+    return common.apply_norm(cfg.norm, params["final_norm"], h), aux
 
 
 # --------------------------------------------------------------------------- #

@@ -165,10 +165,13 @@ def test_choose_split_point_monotone():
 # tasks
 # --------------------------------------------------------------------------- #
 def test_class_logits_gather_position():
-    logits = jnp.arange(2 * 5 * 100, dtype=jnp.float32).reshape(2, 5, 100)
+    model = build_model(CFG)
+    params = model.init(jax.random.PRNGKey(0))
+    h = jax.random.normal(jax.random.PRNGKey(1), (2, 5, CFG.d_model))
     batch = {"tokens": jnp.ones((2, 5), jnp.int32),
              "lengths": jnp.asarray([3, 5], jnp.int32)}
-    cl = tasks.class_logits(logits, batch)
+    cl = tasks.class_logits(model, params, h, batch)
+    logits = model.head(params, h)
     np.testing.assert_allclose(np.asarray(cl[0]),
                                np.asarray(logits[0, 2, 1:78]))
     np.testing.assert_allclose(np.asarray(cl[1]),

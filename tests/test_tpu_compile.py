@@ -198,6 +198,10 @@ def test_fedllm_bucket_update_gpt2(compile_text, topo, mesh_devices):
     # group its time by kernel
     for name in ("lora_matmul_fwd", "flash_attention_fwd"):
         assert name in text
+    # the classification head reads one position and the head's 77
+    # answer-id rows: no array ends in the vocabulary (the embedding is
+    # [V, d])
+    assert f"{cfg.vocab_size}]" not in text
 
 
 def test_fedllm_bucket_update_qwen2(compile_for_chip):
@@ -215,6 +219,7 @@ def test_fedllm_bucket_update_qwen2(compile_for_chip):
     text = compiled.as_text()
     for name in ("tpu_custom_call", "lora_matmul_fwd", "flash_attention_fwd"):
         assert name in text
+    assert f"{cfg.vocab_size}]" not in text      # as on GPT-2
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes \
         < V5E_BYTES_LIMIT
