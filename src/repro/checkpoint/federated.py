@@ -23,14 +23,13 @@ from typing import Optional, Tuple
 
 from repro.checkpoint.manager import CheckpointManager
 from repro.core import metrics as M
+from repro.core.async_agg import Job
 
 
 def save_run(mgr: CheckpointManager, ctx, program, schedule, rnd: int,
              rollovers: int) -> str:
     """Snapshot the run after round ``rnd`` (resume continues at
     ``rnd + 1``)."""
-    from repro.core.async_agg import _Job  # noqa: F401  (restore twin)
-
     state = {
         "round": int(rnd) + 1,
         "rollovers": int(rollovers),
@@ -64,12 +63,10 @@ def restore_run(directory: str, ctx, program, schedule,
                 step: Optional[int] = None) -> Tuple[int, int]:
     """Load the latest (or ``step``-th) snapshot from ``directory`` into
     a freshly constructed run -> (start_round, rollovers)."""
-    from repro.core.async_agg import _Job
-
     st, _ = CheckpointManager(directory).restore_state(step)
     program.load_state_dict(ctx, st["program"])
-    schedule.load_jobs([_Job(int(j["client"]), int(j["start"]),
-                             int(j["arrival"]), j["payload"])
+    schedule.load_jobs([Job(int(j["client"]), int(j["start"]),
+                            int(j["arrival"]), j["payload"])
                         for j in st["jobs"]])
     if st["sched_rngs"] is not None:
         schedule.load_rng_state(st["sched_rngs"])

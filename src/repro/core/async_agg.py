@@ -29,25 +29,27 @@ differs is the payload in flight: LoRA **params** for FedLLM, public-set
 (activations/grad traffic stays synchronous inside the training round:
 the server's half is in the loop while a split client trains).
 
-Since the RoundProgram refactor this module only holds the *model* —
-the delay schedule, the in-flight job bookkeeping and the
-staleness-weighted aggregation — which core/round_program.py's
-``AsyncSchedule`` composes with any framework x executor.  Both
-execution backends therefore share one driver by construction, ledgers
-agree across backends, and heterogeneous ``client_ranks`` compose
-freely with async (stale hetero updates harmonize through
-``aggregate_hetero``).
+This module holds only the *model*: the delay schedule, the in-flight
+``Job`` bookkeeping (``pop_arrivals``) and the staleness weights, which
+core/round_program.py's schedules compose with any framework x
+executor, and ``combine_arrivals``, the host-side combine the round
+driver's parameter fold falls back to for the combines that cannot
+stream (robust order statistics, svd re-factorization of heterogeneous
+ranks).
 """
 from __future__ import annotations
 
 import dataclasses
 from typing import Dict, List
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
-from repro.core import rng as rng_mod
+from repro.core import fed_spmd
 from repro.core.fedavg import fedavg
 from repro.core.heterogeneous import aggregate_hetero
+from repro.peft import lora as lora_lib
 
 
 # --------------------------------------------------------------------------- #
@@ -97,7 +99,7 @@ def staleness_weight(staleness: int, decay: float) -> float:
 
 
 @dataclasses.dataclass
-class _Job:
+class Job:
     """One in-flight client update."""
     client: int
     start: int          # round the client pulled the global + trained
@@ -105,7 +107,7 @@ class _Job:
     payload: object     # params / logits / client-half adapters
 
 
-def _pop_arrivals(in_flight: Dict[int, _Job], rnd: int) -> List[_Job]:
+def pop_arrivals(in_flight: Dict[int, Job], rnd: int) -> List[Job]:
     """Jobs delivering this round, in client visit order."""
     arrived = sorted((j for j in in_flight.values() if j.arrival == rnd),
                      key=lambda j: j.client)
@@ -152,12 +154,6 @@ def robust_stale_combine(global_tree, arrivals, total_weight: float, fed,
     everyone arrives fresh (``rho == 1``) the result is exactly the
     robust combine of the cohort.  Heterogeneous ranks are zero-padded
     to the global rank first (order statistics need one client axis)."""
-    import jax
-    import jax.numpy as jnp
-
-    from repro.core import fed_spmd
-    from repro.peft import lora as lora_lib
-
     trees = []
     for ci, t, _, _ in arrivals:
         if ranks[ci] != fed.lora_rank:
@@ -188,24 +184,3 @@ def combine_arrivals(global_tree, arrivals, total_weight: float, fed,
                                     fed, ranks)
     return stale_weighted_avg(global_tree, arrivals, total_weight, fed,
                               ranks)
-
-
-def _local_rng(fed, rnd: int, ci: int):
-    """Per-(client, round) dropout RNG — kept as an alias of the shared
-    core/rng helper (the single source of truth for the key tree)."""
-    return rng_mod.local_rng(fed, rnd, ci)
-
-
-# --------------------------------------------------------------------------- #
-# Entry point (core/rounds.run_federated dispatches here) — a thin
-# adapter over the unified pipeline
-# --------------------------------------------------------------------------- #
-def run_async(model, base, cfg, fed, targets, public: Dict,
-              clients_data: List[Dict], test: Dict, task: str,
-              batch_size: int, eval_batch: int, verbose: bool,
-              backend: str = "sequential", mesh=None):
-    from repro.core import round_program
-    return round_program.run_program(model, base, cfg, fed, targets,
-                                     public, clients_data, test, task,
-                                     batch_size, eval_batch, verbose,
-                                     backend=backend, mesh=mesh)

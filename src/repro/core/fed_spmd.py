@@ -26,10 +26,10 @@ Per framework:
 
 Clients with ragged batch counts are padded and masked (``valid``): a
 masked step returns the carry unchanged, so every client performs
-exactly the step sequence the sequential backend would.  Host-side
-drivers live in core/rounds_spmd.py; the ``fed_round`` dry-run target
-(launch/dryrun.py --step fed_round) compiles these programs against the
-production meshes.
+exactly the step sequence the sequential backend would.  The runtime
+runs these programs from core/round_program.py's ``SpmdExecutor``; the
+``fed_round`` dry-run target (launch/dryrun.py --step fed_round)
+compiles them against the production meshes.
 """
 from __future__ import annotations
 
@@ -341,8 +341,8 @@ def make_bucket_update(model: Model, fed: FedConfig,
                        task: str = "classification", mesh=None):
     """jit(vmap(local_update)) WITHOUT the closing FedAvg: the building
     block for per-rank bucketing and async participation, where the
-    cross-client aggregation happens on the host across buckets
-    (core/heterogeneous.harmonize_buckets / core/async_agg).  One
+    round driver folds the clients' trees across buckets
+    (core/round_program.FrameworkProgram.aggregate).  One
     program object — jax recompiles per (bucket size, rank, n_steps)
     signature and caches each variant.  ``mesh``: see client_program."""
     local_update = make_local_update(model, fed, task)

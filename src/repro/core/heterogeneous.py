@@ -55,25 +55,6 @@ def aggregate_hetero(trees: List, ranks: Sequence[int], alpha: float,
     raise ValueError(method)
 
 
-def harmonize_buckets(bucket_trees, bucket_clients, ranks: Sequence[int],
-                      alpha: float, global_rank: int, weights,
-                      method: str = "zeropad"):
-    """Cross-bucket harmonization for the SPMD backend's per-rank
-    bucketing (core/rounds_spmd.py): ``bucket_trees[k]`` is a list of
-    per-client LoRA trees for the clients in ``bucket_clients[k]``.
-    Reassembles all clients into visit order and runs the same
-    ``aggregate_hetero`` the sequential backend uses, so both backends
-    share one harmonization code path."""
-    by_client = {}
-    for trees, clients in zip(bucket_trees, bucket_clients):
-        for ci, t in zip(clients, trees):
-            by_client[ci] = t
-    order = sorted(by_client)
-    return aggregate_hetero([by_client[ci] for ci in order],
-                            [ranks[ci] for ci in order], alpha, global_rank,
-                            [weights[ci] for ci in order], method)
-
-
 def _svd_aggregate(trees, ranks, alpha, global_rank, weights):
     if weights is None:
         weights = [1.0] * len(trees)

@@ -1,8 +1,8 @@
 """The composable federated round pipeline — ONE driver for every
 engine combination the paper's comparison needs:
 
-    framework     x   backend      x   aggregation   (+ privacy, hetero)
-    fedllm/kd/split   sequential/spmd  sync/async
+    framework     x   backend             x   aggregation   (+ privacy, hetero)
+    fedllm/kd/split   sequential/spmd/cohort  sync/async
 
 One federated round decomposes into the paper's canonical stages
 
@@ -11,12 +11,20 @@ One federated round decomposes into the paper's canonical stages
 and the combination axes are orthogonal pieces composed by
 ``run_program``:
 
-- A **FrameworkProgram** (FedLLM / KD / Split) contributes the stage
-  bodies: what a client computes, what crosses the wire (payload +
-  shape-derived bytes), and how the server fuses arrivals.  The same
-  stage-specs hand the launch layer its jittable round programs
-  (``FrameworkProgram.spmd_round`` — launch/steps.py builds the
-  ``fed_round`` dry-run artifacts from them).
+- A **FrameworkProgram** subclass (FedLLM / KD / Split) contributes the
+  stage bodies: what a client computes, what crosses the wire (payload
+  + shape-derived bytes), and how the server folds one group of
+  arrivals (``agg_fold``) and closes the round (``agg_finalize``).  The
+  aggregation stage is the base's ``FrameworkProgram.aggregate``, one
+  path on every backend: the round's kept arrivals come in one group
+  per secure-agg masking cohort — one per start round on ``sequential``
+  and ``spmd`` (a single group under sync), one per start chunk under
+  ``cohort`` — and fold in turn into one fp32 accumulator (jitted,
+  donated ``_fold_add``); robust and svd combines, which cannot
+  stream, buffer the round's arrivals instead.  The same stage-specs
+  hand the launch layer its jittable round programs (``spmd_round`` —
+  launch/steps.py builds the ``fed_round`` dry-run artifacts from
+  them).
 - An **Executor** decides how per-client work runs.  ``sequential``
   loops clients (the paper-literal reference); ``spmd`` stacks the
   round's ready-set on a leading client axis and runs one jitted
@@ -28,11 +36,9 @@ and the combination axes are orthogonal pieces composed by
   just in the dry-run.  ``cohort`` (CohortStreamingExecutor) is the
   million-virtual-client path: the round's ready set streams through
   the same SPMD stage programs ``FedConfig.cohort_size`` clients at a
-  time, jitted donated-buffer folds carry the partial aggregates
-  (weighted param/logit sums, ledger counters, per-chunk secure-agg
-  cohorts) between chunks, and clients come from a lazy
-  ``data/population.ClientPopulation`` — peak memory is ONE cohort, no
-  full-fleet array ever exists.  Under a hierarchical topology
+  time, each chunk its own secure-agg masking cohort, and clients come
+  from a lazy ``data/population.ClientPopulation`` — peak memory is ONE
+  cohort, no full-fleet array ever exists.  Under a hierarchical topology
   (``FedConfig.n_edges`` or a multi-pod mesh) the ledger splits wire
   accounting into client->edge and edge->server hops.
 - A **Schedule** decides when uploads arrive: ``SyncSchedule`` delivers
@@ -72,6 +78,9 @@ from repro.core import kd as kd_mod
 from repro.core import metrics as M
 from repro.core import rng as rng_mod
 from repro.core import split as split_mod
+from repro.core.async_agg import (Job, ParticipationSchedule,
+                                  combine_arrivals, pop_arrivals,
+                                  staleness_weight)
 from repro.core.fedavg import evaluate, make_fns
 from repro.core.heterogeneous import normalize_ranks
 from repro.data import population as population_mod
@@ -213,8 +222,7 @@ class SyncSchedule:
         """``extra_delay`` is a fault-injected straggler lag: the upload
         arrives that many rounds late and flows through the staleness
         weighting like any async arrival."""
-        from repro.core.async_agg import _Job
-        self._pending.append(_Job(ci, rnd, rnd + extra_delay, payload))
+        self._pending.append(Job(ci, rnd, rnd + extra_delay, payload))
 
     def pop_arrivals(self, rnd: int):
         out = sorted((j for j in self._pending if j.arrival == rnd),
@@ -242,7 +250,6 @@ class AsyncSchedule:
     seeded per-job delay (core/async_agg.ParticipationSchedule)."""
 
     def __init__(self, fed: FedConfig, n_clients: int):
-        from repro.core.async_agg import ParticipationSchedule
         self.n = n_clients
         self.sched = ParticipationSchedule(n_clients, fed.seed + 17,
                                            fed.max_staleness)
@@ -252,13 +259,11 @@ class AsyncSchedule:
         return [ci for ci in range(self.n) if ci not in self.in_flight]
 
     def submit(self, rnd: int, ci: int, payload, extra_delay: int = 0):
-        from repro.core.async_agg import _Job
-        self.in_flight[ci] = _Job(
+        self.in_flight[ci] = Job(
             ci, rnd, rnd + self.sched.next_delay(ci) + extra_delay, payload)
 
     def pop_arrivals(self, rnd: int):
-        from repro.core.async_agg import _pop_arrivals
-        return _pop_arrivals(self.in_flight, rnd)
+        return pop_arrivals(self.in_flight, rnd)
 
     # -- checkpoint/resume (checkpoint/federated.py) --------------------- #
     def jobs(self):
@@ -611,13 +616,12 @@ def _cohort_uid(rnd: int, idx: int) -> int:
 
 
 def _stream_fold_params(ctx, state, kept, global_tree):
-    """Shared FedLLM/Split streaming a4/cc2 fold: one arrival chunk
+    """Shared FedLLM/Split a4/cc2 fold: one masking group of arrivals
     into the running staleness-weighted parameter sum.  The zeropad
     hetero path is linear per leaf, so it streams chunk-by-chunk in one
     fp32 accumulator; svd re-factorization is not, so it buffers the
     round's arrivals instead (documented O(arrivals-this-round)
     exception to the one-cohort memory bound)."""
-    from repro.core.async_agg import staleness_weight
     fed = ctx.fed
     if not kept:
         return state
@@ -651,7 +655,6 @@ def _finalize_param_fold(ctx, state, global_tree):
     if state is None:
         return global_tree
     if state[0] == "buf":
-        from repro.core.async_agg import combine_arrivals
         return combine_arrivals(global_tree, state[1], ctx.total_w,
                                 ctx.fed, ctx.ranks)
     _, acc, w_sum, raw = state
@@ -696,7 +699,40 @@ EXECUTORS = {"sequential": SequentialExecutor, "spmd": SpmdExecutor,
 # --------------------------------------------------------------------------- #
 # Framework stage-specs
 # --------------------------------------------------------------------------- #
-class FedLLMProgram:
+class FrameworkProgram:
+    """One framework's stage bodies, composed by ``run_program``.  A
+    subclass gives ``broadcast``, ``local_update``, ``upload``,
+    ``record_arrival``, the ``agg_fold`` / ``agg_finalize`` pair,
+    ``edge_payload_bytes``, ``evaluate``, ``final_state``,
+    ``state_dict`` / ``load_state_dict`` for crash recovery, and the
+    launch layer's ``spmd_round``.  The aggregation stage itself is the
+    base's ``aggregate``, the same on every backend."""
+
+    def aggregate(self, ctx, ex, kept, arrived, rnd):
+        """The server's aggregation stage, called once a round.
+        ``kept`` holds the round's kept arrivals ``(client, payload,
+        staleness, data weight)`` in one group per secure-agg masking
+        cohort (one per start round on the flat backends, so a single
+        group under sync; one per start chunk under cohort streaming);
+        each group folds into the running state in turn, then
+        ``agg_finalize`` closes the round over the ``arrived`` client
+        ids.  Both are empty when the round rolls over."""
+        state = self.agg_init(ctx)
+        for group in kept:
+            state = self.agg_fold(ctx, ex, state, group, rnd)
+        self.agg_finalize(ctx, ex, state, arrived, rnd)
+
+    def agg_init(self, ctx):
+        return None
+
+    def payload_bytes(self, ctx, payload) -> int:
+        return M.tree_bytes(payload)
+
+    def payload_arrays(self, payload):
+        return jax.tree.leaves(payload)
+
+
+class FedLLMProgram(FrameworkProgram):
     """FedLLMs (paper SSII.A): a1 broadcast global LoRA params, a2 local
     PEFT fine-tuning, a3 upload the tuned params, a4 FedAvg."""
 
@@ -745,23 +781,7 @@ class FedLLMProgram:
             ctx.ledger.record(rnd, job.client, "dp_meta", M.UP,
                               M.DP_META_BYTES)
 
-    def payload_bytes(self, ctx, payload) -> int:
-        return M.tree_bytes(payload)
-
-    def payload_arrays(self, payload):
-        return jax.tree.leaves(payload)
-
-    def aggregate(self, ctx, ex, kept, arrived, rnd):
-        from repro.core.async_agg import combine_arrivals
-        if kept:
-            self.global_lt = combine_arrivals(self.global_lt, kept,
-                                              ctx.total_w, ctx.fed,
-                                              ctx.ranks)
-
-    # -- streaming a4 (cohort executor): fold chunks, finalize once --- #
-    def agg_init(self, ctx):
-        return None
-
+    # -- a4: the parameter fold ---------------------------------------- #
     def agg_fold(self, ctx, ex, state, kept, rnd):
         return _stream_fold_params(ctx, state, kept, self.global_lt)
 
@@ -794,7 +814,7 @@ class FedLLMProgram:
         return fed_spmd.make_spmd_round(model, fed, task, n_edges=n_edges)
 
 
-class KDProgram:
+class KDProgram(FrameworkProgram):
     """KD-FedLLMs (paper SSII.B): params never cross the wire — clients
     upload public-set logits (b3), the server fuses knowledge (b4),
     distills (b5), and re-broadcasts global knowledge (b6-b8)."""
@@ -858,51 +878,13 @@ class KDProgram:
     def payload_arrays(self, payload):
         return [payload[0]]
 
-    def aggregate(self, ctx, ex, kept, arrived, rnd):
-        from repro.core.async_agg import staleness_weight
-        fed = ctx.fed
-        if kept:
-            ws = [w * staleness_weight(s, fed.staleness_decay)
-                  for _, _, s, w in kept]
-            if fed.robust_agg != "mean":
-                # b4 under a robust combine: order statistics over the
-                # stacked client logits instead of the weighted mean
-                teacher = fed_spmd.robust_client_combine(
-                    jnp.stack([jnp.asarray(p[0], jnp.float32)
-                               for _, p, _, _ in kept]),
-                    jnp.asarray(ws, jnp.float32), fed.robust_agg,
-                    fed.trim_frac, fed.clip_norm)
-            else:
-                teacher = kd_mod.aggregate_knowledge(
-                    [p[0] for _, p, _, _ in kept], ws)
-            self.server_lt, self.server_opt, _ = kd_mod.distill(
-                ctx.fns, ctx.base, self.server_lt, self.server_opt,
-                ctx.public, teacher, fed.kd_epochs, ctx.eval_batch,
-                seed=fed.seed + rnd)
-            self.glob = kd_mod.client_logits(ctx.fns, ctx.base,
-                                             self.server_lt, ctx.public,
-                                             ctx.eval_batch)
-        # b6-b8: delivering clients re-sync against the latest knowledge
-        if arrived and self.glob is not None:
-            glob_wire = kd_mod.logit_wire_bytes(self.glob.shape, fed)
-            cis = [j.client for j in arrived]
-            for ci in cis:
-                ctx.ledger.record(rnd, ci, "logits", M.DOWN, glob_wire)
-                ctx.cost[ci].add_train(ctx.cfg, self.pub_tok * fed.kd_epochs,
-                                       self.n_lora[ci])
-            ex.kd_distill(self, cis, self.glob, rnd)
-
-    # -- streaming b4-b8 (cohort executor) ---------------------------- #
-    def agg_init(self, ctx):
-        return None
-
+    # -- b4-b8 ---------------------------------------------------------- #
     def agg_fold(self, ctx, ex, state, kept, rnd):
-        """Fold one arrival chunk's logits into the running b4 teacher
+        """Fold one masking group's logits into the running b4 teacher
         sum (the weighted mean is linear, so it streams exactly).  A
         robust combine is not linear, so it buffers the round's
         arrivals instead — the same documented O(arrivals-this-round)
         exception the svd harmonizer makes."""
-        from repro.core.async_agg import staleness_weight
         if not kept:
             return state
         if ctx.fed.robust_agg != "mean":
@@ -923,9 +905,9 @@ class KDProgram:
 
     def agg_finalize(self, ctx, ex, state, arrived, rnd):
         """b5 server distill from the normalized teacher, then the
-        b6-b8 re-sync streamed over the arrived clients in cohort-sized
-        chunks (one stacked distill program per chunk)."""
-        from repro.core.async_agg import staleness_weight
+        b6-b8 re-sync of the arrived clients against the latest global
+        knowledge — streamed in cohort-sized chunks under cohort
+        streaming (one stacked distill program per chunk)."""
         fed = ctx.fed
         if state is not None and isinstance(state[0], str):   # robust buffer
             ws = [w * staleness_weight(s, fed.staleness_decay)
@@ -946,7 +928,8 @@ class KDProgram:
                                              ctx.eval_batch)
         if arrived and self.glob is not None:
             glob_wire = kd_mod.logit_wire_bytes(self.glob.shape, fed)
-            for chunk in _cohort_chunks(arrived, fed.cohort_size):
+            for chunk in _cohort_chunks(
+                    arrived, fed.cohort_size if ex.streaming else 0):
                 for ci in chunk:
                     ctx.ledger.record(rnd, ci, "logits", M.DOWN, glob_wire)
                     ctx.cost[ci].add_train(ctx.cfg,
@@ -1027,7 +1010,7 @@ class KDProgram:
         return kd_round_core
 
 
-class SplitProgram:
+class SplitProgram(FrameworkProgram):
     """Split-FedLLMs (paper SSII.C): c1-c5 split training (activations
     up, gradients down, server half in the loop) plus the cc1-cc4
     FedAvg of the *client-side* adapters."""
@@ -1094,24 +1077,7 @@ class SplitProgram:
         ctx.ledger.record(rnd, job.client, "lora_params", M.UP,
                           M.tree_bytes(job.payload))                   # cc1
 
-    def payload_bytes(self, ctx, payload) -> int:
-        return M.tree_bytes(payload)
-
-    def payload_arrays(self, payload):
-        return jax.tree.leaves(payload)
-
-    def aggregate(self, ctx, ex, kept, arrived, rnd):
-        from repro.core.async_agg import combine_arrivals
-        if kept:                                                       # cc2
-            self.c_global = combine_arrivals(self.c_global, kept,
-                                             ctx.total_w, ctx.fed,
-                                             ctx.ranks)
-        self.joined = split_mod.join_lora(self.c_global, self.s_lt)
-
-    # -- streaming cc2 (cohort executor) ------------------------------ #
-    def agg_init(self, ctx):
-        return None
-
+    # -- cc2: the parameter fold over the client halves ----------------- #
     def agg_fold(self, ctx, ex, state, kept, rnd):
         return _stream_fold_params(ctx, state, kept, self.c_global)
 
@@ -1191,7 +1157,7 @@ def run_program(model, base, cfg: ModelConfig, fed: FedConfig, targets,
     program = PROGRAMS[fed.framework](ctx)
     ex = EXECUTORS[backend](ctx, mesh)
     schedule = make_schedule(fed, ctx.n_clients)
-    streaming = getattr(ex, "streaming", False)
+    streaming = ex.streaming
     if streaming:
         from repro.launch import mesh as mesh_lib
         n_edges = fed.n_edges or mesh_lib.n_edges(mesh)
@@ -1240,8 +1206,8 @@ def run_program(model, base, cfg: ModelConfig, fed: FedConfig, targets,
 
     def _screen(arrivals):
         """Validation verdicts for the whole round's arrivals at once
-        (norm screen medians are round-global, so flat and streaming
-        drivers quarantine the identical set)."""
+        (norm screen medians are round-global, so every backend
+        quarantines the identical set)."""
         if not arrivals:
             return []
         from repro.faults import guard as fault_guard
@@ -1254,50 +1220,47 @@ def run_program(model, base, cfg: ModelConfig, fed: FedConfig, targets,
                           program.payload_bytes(ctx, j.payload))
         ctx.secagg.discard(ctx.secagg_start(j.start, j.client), j.client)
 
-    def _aggregate_streaming(rnd, starters):
-        """Arrivals of a streaming round -> (arrived, rolled over)."""
-        # group arrivals by masking cohort (insertion order), fold
-        # each group into the running partial aggregate and free its
-        # secagg payloads before touching the next — peak memory is
-        # one cohort of payloads plus one fp32 accumulator
+    def _aggregate(rnd, starters):
+        """The round's arrivals -> (arrived, rolled over).  Arrivals are
+        screened round-globally, then handled one secure-agg masking
+        cohort at a time (the start round on the flat backends, the
+        start chunk under cohort streaming): quarantine, wire records,
+        the staleness cut and mask delivery.  The program then folds
+        the kept groups in one ``aggregate`` call."""
         arrivals = schedule.pop_arrivals(rnd)
         ok = _screen(arrivals)
-        n_kept = sum(1 for j, good in zip(arrivals, ok)
-                     if good and rnd - j.start <= fed.max_staleness)
-        roll = bool(fed.quorum > 0 and starters
-                    and n_kept < fed.quorum * len(starters))
         groups: Dict[int, List] = {}
         for j, good in zip(arrivals, ok):
             groups.setdefault(ctx.secagg_start(j.start, j.client),
                               []).append((j, good))
-        state = program.agg_init(ctx)
-        arrived_cis, used_edges = [], set()
+        kept, arrived, used_edges = [], [], set()
         for gi, (gkey, gjobs) in enumerate(groups.items()):
-            kept_chunk, delivered = [], []
+            group, delivered = [], []
             for j, good in gjobs:
                 if not good:
                     _quarantine(j, rnd)
                     continue
-                arrived_cis.append(j.client)
+                arrived.append(j.client)
                 program.record_arrival(ctx, j, rnd)
                 s = rnd - j.start
                 if s <= fed.max_staleness:
-                    kept_chunk.append((j.client, j.payload, s,
-                                       ctx.data_w[j.client]))
+                    group.append((j.client, j.payload, s,
+                                  ctx.data_w[j.client]))
                     delivered.append((gkey, j.client))
                 else:
                     ctx.secagg.discard(gkey, j.client)
             ctx.secagg.deliver(ctx.ledger, rnd, delivered)
-            if not roll:
-                state = program.agg_fold(ctx, ex, state, kept_chunk, rnd)
+            kept.append(group)
             used_edges.add(gi % n_edges)
+        roll = bool(fed.quorum > 0 and starters and sum(map(len, kept))
+                    < fed.quorum * len(starters))
         if roll:
-            # below quorum: the cohort's payloads were received and
-            # their secure-agg masks settled, but the round rolls
-            # over — nothing folds into the global state
-            state, arrived_cis = None, []
-        program.agg_finalize(ctx, ex, state, arrived_cis, rnd)
-        if hierarchical and arrived_cis:
+            # below quorum: the payloads were received and their
+            # secure-agg masks settled, but the round rolls over —
+            # nothing folds into the global state
+            kept, arrived = [], []
+        program.aggregate(ctx, ex, kept, arrived, rnd)
+        if hierarchical and arrived:
             # second hop: each edge that aggregated a cohort this
             # round forwards one fused payload up and pulls the new
             # global down (negative ids denote edge aggregators)
@@ -1307,39 +1270,11 @@ def run_program(model, base, cfg: ModelConfig, fed: FedConfig, targets,
                                   eb, hop=M.EDGE_SERVER)
                 ctx.ledger.record(rnd, -(e + 1), "edge_agg", M.DOWN,
                                   eb, hop=M.EDGE_SERVER)
-        return len(arrived_cis), roll
-
-    def _aggregate_flat(rnd, starters):
-        """Arrivals of a flat round -> (arrived, rolled over)."""
-        arrivals = schedule.pop_arrivals(rnd)
-        ok = _screen(arrivals)
-        kept, delivered, arrived = [], [], []
-        for j, good in zip(arrivals, ok):
-            if not good:
-                _quarantine(j, rnd)
-                continue
-            arrived.append(j)
-            program.record_arrival(ctx, j, rnd)
-            s = rnd - j.start
-            if s <= fed.max_staleness:
-                kept.append((j.client, j.payload, s,
-                             ctx.data_w[j.client]))
-                delivered.append((j.start, j.client))
-            else:
-                ctx.secagg.discard(j.start, j.client)
-        ctx.secagg.deliver(ctx.ledger, rnd, delivered)
-        roll = bool(fed.quorum > 0 and starters
-                    and len(kept) < fed.quorum * len(starters))
-        if roll:
-            kept, arrived = [], []
-        program.aggregate(ctx, ex, kept, arrived, rnd)
         return len(arrived), roll
 
     # -- crash recovery -------------------------------------------------- #
     mgr = None
     if checkpoint_every and checkpoint_every > 0:
-        if not checkpoint_dir:
-            raise ValueError("checkpoint_every requires checkpoint_dir")
         from repro.checkpoint.manager import CheckpointManager
         mgr = CheckpointManager(checkpoint_dir)
     start_rnd, rollovers = 0, 0
@@ -1355,27 +1290,25 @@ def run_program(model, base, cfg: ModelConfig, fed: FedConfig, targets,
             # secure-agg masking cohort (payloads are created — and masked
             # — now, even when they deliver rounds later)
             starters = schedule.starters(rnd)
-            if streaming:
-                # the ready set streams through the stacked programs one
-                # cohort-sized chunk at a time; each chunk is its own
-                # secure-agg masking cohort so its payloads can be freed
-                # the moment the whole chunk delivers
-                for k, chunk in enumerate(
-                        _cohort_chunks(starters, fed.cohort_size)):
+            # under cohort streaming the ready set streams through the
+            # stacked programs one cohort-sized chunk at a time, each
+            # chunk its own secure-agg masking cohort so its payloads can
+            # be freed the moment the whole chunk delivers; the flat
+            # backends start one chunk keyed by the start round
+            for k, chunk in enumerate(_cohort_chunks(
+                    starters, fed.cohort_size if streaming else 0)):
+                cid = None
+                if streaming:
                     cid = _cohort_uid(rnd, k)
                     for ci in chunk:
                         ctx._cohort_ids[(rnd, ci)] = cid
-                    ctx.secagg.begin_cohort(ctx.ledger, rnd, chunk,
-                                            cohort_id=cid)
-                    _start(chunk, rnd)
-            else:
-                ctx.secagg.begin_cohort(ctx.ledger, rnd, starters)
-                _start(starters, rnd)
+                ctx.secagg.begin_cohort(ctx.ledger, rnd, chunk,
+                                        cohort_id=cid)
+                _start(chunk, rnd)
             # arrivals: record wire traffic, drop too-stale updates (their
             # pairwise masks recovered like any absent cohort member's)
             with obs.span("fl.aggregate", round=rnd):
-                arrived_n, rolled = (_aggregate_streaming if streaming
-                                     else _aggregate_flat)(rnd, starters)
+                arrived_n, rolled = _aggregate(rnd, starters)
             rollovers += int(rolled)
             acc, loss = program.evaluate(ctx)
             flops = float(np.mean([c.flops for c in ctx.cost])) \

@@ -155,10 +155,10 @@ def run_one(arch: str, shape_name: str, multi_pod: bool = False,
                 rec["secagg_key_bytes_per_client"] = up + down
 
     # Heterogeneous client_ranks compile one stacked program per rank
-    # bucket (core/rounds_spmd.py runs exactly these per-bucket
-    # programs).  Split buckets only contiguous equal-rank runs — the
-    # shared server half is carried client-after-client — so its
-    # program set comes from rank_segments, like the runtime's.
+    # bucket (core/round_program.SpmdExecutor runs exactly these
+    # per-bucket programs).  Split buckets only contiguous equal-rank
+    # runs — the shared server half is carried client-after-client — so
+    # its program set comes from rank_segments, like the runtime's.
     builds = [("", {})]
     if step == "fed_round" and client_ranks:
         from repro.core import fed_spmd

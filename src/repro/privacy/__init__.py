@@ -3,8 +3,8 @@ simulated secure aggregation — the paper's research-direction axes
 (SSVI; FedLLM survey arXiv:2503.12016) as first-class scenario knobs.
 
 Configured by ``configs/base.PrivacyConfig`` (``FedConfig.privacy``);
-wired through every round engine (core/{rounds,rounds_spmd,async_agg})
-uniformly over the three frameworks, both execution backends and both
+wired through the one round driver (core/round_program.py) uniformly
+over the three frameworks, every execution backend and both
 aggregation schedules.  Per-framework threat surfaces:
 
 ==========  =========================  ================================

@@ -4,7 +4,7 @@ within fp32 tolerance (vmapped/batched reductions reorder float ops) and
 the communication ledger byte-for-byte (all wire sizes are
 shape-derived).  Dropout is 0 here: with dropout the backends draw
 different (equally valid) mask streams and bit-level parity is
-undefined (see core/rounds_spmd.py)."""
+undefined (see core/rng.py)."""
 import dataclasses
 
 import numpy as np

@@ -31,7 +31,7 @@ def test_local_rng_pinned_to_legacy_formula():
 
 def test_grid_keys_pinned_to_legacy_formula():
     """The (C, S) dropout grid the SPMD executor consumes is exactly
-    split(local_rng) per row — the old rounds_spmd._grid_keys."""
+    split(local_rng) per row, as the SPMD executor always drew it."""
     fed = _fed(seed=11)
     cis, n_steps = [0, 2, 5], 4
     grid = rng.grid_keys(fed, 3, cis, n_steps)
@@ -40,14 +40,6 @@ def test_grid_keys_pinned_to_legacy_formula():
             jax.random.PRNGKey(fed.seed * 1013 + 3 * 131 + ci), n_steps)
         np.testing.assert_array_equal(np.asarray(grid[k]),
                                       np.asarray(want))
-
-
-def test_async_agg_alias_is_the_shared_helper():
-    from repro.core import async_agg
-    fed = _fed()
-    np.testing.assert_array_equal(
-        np.asarray(async_agg._local_rng(fed, 4, 2)),
-        np.asarray(rng.local_rng(fed, 4, 2)))
 
 
 def test_noise_key_pinned_to_legacy_fold_chain():

@@ -14,6 +14,7 @@ from repro.configs.base import FedConfig, ModelConfig
 from repro.core import round_program as rp
 from repro.core.rounds import run_federated
 from repro.data import banking77, partition
+from repro.data.population import ClientPopulation
 
 CFG = ModelConfig(name="rp-t", family="dense", n_layers=2, d_model=32,
                   n_heads=2, n_kv_heads=2, d_ff=64, vocab_size=192,
@@ -210,16 +211,92 @@ def test_spmd_runtime_on_four_device_mesh_matches_unsharded():
 
 
 def test_adapters_contain_no_per_driver_threading():
-    """The acceptance clause: core/rounds*.py are adapters only — no
+    """The acceptance clause: core/rounds.py is an adapter only — no
     privacy/hetero/async code paths left behind."""
     import inspect
 
-    from repro.core import rounds, rounds_spmd
+    from repro.core import rounds
 
-    for mod in (rounds, rounds_spmd):
-        src = inspect.getsource(mod)
-        for banned in ("privatize", "SecureAggSession", "secagg.",
-                       "stale_weighted_avg", "rank_buckets",
-                       "rank_segments", "harmonize_buckets",
-                       "ParticipationSchedule"):
-            assert banned not in src, (mod.__name__, banned)
+    src = inspect.getsource(rounds)
+    for banned in ("privatize", "SecureAggSession", "secagg.",
+                   "stale_weighted_avg", "rank_buckets",
+                   "rank_segments", "ParticipationSchedule"):
+        assert banned not in src, banned
+
+
+# --------------------------------------------------------------------------- #
+# One aggregation path: FrameworkProgram.aggregate on every backend
+# --------------------------------------------------------------------------- #
+def _recording_fedllm(calls, updates):
+    """The FedLLM program, recording every ``aggregate`` call (its round
+    and the global it leaves) and what each ``local_update`` returned."""
+
+    class Recording(rp.PROGRAMS["fedllm"]):
+        def local_update(self, ctx, ex, jobs, rnd):
+            outs = super().local_update(ctx, ex, jobs, rnd)
+            updates.append(outs)
+            return outs
+
+        def aggregate(self, ctx, ex, kept, arrived, rnd):
+            super().aggregate(ctx, ex, kept, arrived, rnd)
+            calls.append((rnd, self.global_lt))
+    return Recording
+
+
+@pytest.mark.parametrize("engine,rollovers", [
+    (dict(backend="sequential"), 0),
+    (dict(backend="spmd"), 0),
+    (dict(backend="cohort", cohort_size=2), 0),
+    (dict(backend="spmd", aggregation="async", max_staleness=2), 0),
+    (dict(backend="spmd", aggregation="async", max_staleness=2,
+          quorum=1.0), 1),
+], ids=["sequential", "spmd", "cohort", "spmd-async", "spmd-async-quorum"])
+def test_aggregate_called_once_per_round(monkeypatch, engine, rollovers):
+    """Every backend aggregates through the one ``aggregate`` call a
+    round — a round that rolls over below quorum included — and the
+    global it leaves is the run's result."""
+    pub = banking77.generate(24, CFG.vocab_size, 12, seed=0)
+    tr = banking77.generate(96, CFG.vocab_size, 12, seed=1)
+    te = banking77.generate(16, CFG.vocab_size, 12, seed=2)
+    clients = ClientPopulation.from_clients_data(
+        partition.iid_partition(tr, 4, seed=0))
+    calls = []
+    monkeypatch.setitem(rp.PROGRAMS, "fedllm", _recording_fedllm(calls, []))
+    res = run_federated(CFG, _fed(n_clients=4, rounds=2, **engine), pub,
+                        clients, te, batch_size=8, eval_batch=8)
+    assert res.rollovers == rollovers
+    assert [rnd for rnd, _ in calls] == [0, 1]
+    for a, b in zip(jax.tree.leaves(calls[-1][1]),
+                    jax.tree.leaves(res.final_lora)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_spmd_aggregate_is_the_data_weighted_mean(monkeypatch):
+    """The chip cells' path (spmd, sync, FedLLM, mean): the new global
+    is the data-weighted mean of the trees local training returned,
+    over shards of unequal size."""
+    pub = banking77.generate(24, CFG.vocab_size, 12, seed=0)
+    tr = banking77.generate(80, CFG.vocab_size, 12, seed=1)
+    te = banking77.generate(16, CFG.vocab_size, 12, seed=2)
+    sizes = [16, 24, 40]
+    edges = np.cumsum([0] + sizes)
+    clients = ClientPopulation.from_clients_data(
+        [{k: v[a:b] for k, v in tr.items()}
+         for a, b in zip(edges[:-1], edges[1:])])
+    calls, updates = [], []
+    monkeypatch.setitem(rp.PROGRAMS, "fedllm",
+                        _recording_fedllm(calls, updates))
+    run_federated(CFG, _fed(backend="spmd"), pub, clients, te,
+                  batch_size=8, eval_batch=8)
+    (outs,) = updates
+    assert [ci for ci, _ in outs] == [0, 1, 2]
+    w = np.asarray(sizes, np.float64) / sum(sizes)
+    trees = [jax.tree.leaves(t) for _, t in outs]
+    for k, got in enumerate(jax.tree.leaves(calls[0][1])):
+        xs = [np.asarray(t[k], np.float64) for t in trees]
+        want = sum(wi * x for wi, x in zip(w, xs))
+        # float32 summation error is relative to the terms' magnitude,
+        # not to a mean that cancels to near zero
+        scale = sum(wi * np.abs(x) for wi, x in zip(w, xs))
+        assert np.all(np.abs(np.asarray(got, np.float64) - want)
+                      <= 1e-6 * scale), k
